@@ -30,6 +30,7 @@ from repro.core.lsketch import insert_window_batch
 from repro.core.queries import edge_query
 from repro.core.ref_prime import PrimeLSketch
 from repro.engine import insert as eng_insert
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import (merge_bench as _merge_bench,
                      timed_medians as _timed_medians, timer, write_csv)
@@ -90,9 +91,9 @@ def engine_insert_throughput(n=20000, subwindows_spanned=8,
 
       * fori_chunked  — legacy host split loop, one dispatch per boundary;
       * scan_fused    — engine single-dispatch ``lax.scan`` path;
-      * pallas_binned — engine dispatch with the block-binned kernel
-                        (interpret mode on CPU — structural check, not a
-                        CPU speed claim).
+      * pallas_binned — engine dispatch with the block-binned path
+                        (the XLA key walk off TPU — a structural
+                        check, not a CPU speed claim).
 
     Emits ``BENCH_engine.json`` next to the repo root.
     """
@@ -834,7 +835,6 @@ def telemetry_mesh_throughput(steps=4, n_experts=64, n_shards=8):
     its mesh-resident reads accordingly (telemetry/router_sketch.py).
     """
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import merge as _merge
     from repro.core.queries import vertex_query
@@ -864,8 +864,8 @@ def telemetry_mesh_throughput(steps=4, n_experts=64, n_shards=8):
                                 direction="in", with_edge_label=False,
                                 last=None)
             return w
-        return shard_map(body, mesh=mesh, in_specs=P("data"),
-                         out_specs=P(), check_rep=False)(shards)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                             out_specs=P(), check_vma=False)(shards)
 
     variants = [
         ("telemetry_handle", lambda: jax.block_until_ready(
@@ -888,10 +888,21 @@ def telemetry_mesh_throughput(steps=4, n_experts=64, n_shards=8):
     return rows
 
 
-def mesh_rows_subprocess(quick: bool) -> None:
-    """Run the mesh-resident rows in a child with 8 fake CPU devices (the
-    device count is fixed at backend init, so the parent can't host them).
-    The child merges its rows into BENCH_engine.json itself."""
+def mesh_rows(quick: bool) -> None:
+    """The mesh-resident rows (collective query + telemetry decision).
+
+    On the CPU backend they run in a child with 8 fake CPU devices (the
+    device count is fixed at backend init, so the parent can't host
+    them); the child merges its rows into BENCH_engine.json itself. On an
+    accelerator a child could not reach the chips this process holds, so
+    the rows run here, on the real devices, or not at all."""
+    if jax.default_backend() != "cpu":
+        n_dev = len(jax.devices())
+        if n_dev < 8:
+            raise SystemExit(f"the mesh rows lay 8 shards over 8 devices; "
+                             f"this host has {n_dev}")
+        _mesh_rows_here(quick)
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
@@ -900,6 +911,15 @@ def mesh_rows_subprocess(quick: bool) -> None:
         cmd.append("--quick")
     subprocess.run(cmd, check=True, env=env,
                    cwd=Path(__file__).resolve().parents[1])
+
+
+def _mesh_rows_here(quick: bool) -> None:
+    n = 2048 if quick else 16384
+    for rows in (collective_query_throughput(n=n, q=1024 if quick else 2048),
+                 telemetry_mesh_throughput()):
+        print("impl,...,total_s")
+        for r in rows:
+            print(",".join(str(x) for x in r))
 
 
 def query_throughput(n=20000, q=4096):
@@ -929,7 +949,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke sizes (seconds, not minutes)")
     ap.add_argument("--no-pallas", action="store_true",
-                    help="skip the interpret-mode Pallas comparison")
+                    help="skip the block-binned (pallas) comparison")
     ap.add_argument("--only-query", action="store_true",
                     help="run only the query-path rows (the conformance "
                          "job's bench: feeds check_bench + the artifact "
@@ -941,16 +961,12 @@ def main(argv=None):
                     help="internal: run the mesh rows in this process "
                          "(expects the fake-device XLA_FLAGS already set)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # power-of-two sizes: the fused path buckets batch shapes, so an
     # aligned n measures the paths on identical item counts
     n = 2048 if args.quick else 16384
     if args.mesh_child:
-        for rows in (collective_query_throughput(
-                n=n, q=1024 if args.quick else 2048),
-                telemetry_mesh_throughput()):
-            print("impl,...,total_s")
-            for r in rows:
-                print(",".join(str(x) for x in r))
+        _mesh_rows_here(args.quick)
         return
     if args.only_query:
         qrows = query_path_throughput(n=n, q=1024 if args.quick else 2048)
@@ -978,7 +994,7 @@ def main(argv=None):
         from .serve_bench import run_all as _serve_rows
         _serve_rows(quick=args.quick)
         if not args.no_mesh:
-            mesh_rows_subprocess(args.quick)
+            mesh_rows(args.quick)
         return
     rows = engine_insert_throughput(n=n, subwindows_spanned=4,
                                     include_pallas=not args.no_pallas)
@@ -1019,7 +1035,7 @@ def main(argv=None):
     from .serve_bench import run_all as _serve_rows
     _serve_rows(quick=args.quick)
     if not args.no_mesh:
-        mesh_rows_subprocess(args.quick)
+        mesh_rows(args.quick)
     if not args.quick:
         insert_throughput(n=n)
         query_throughput(n=n)

@@ -190,7 +190,8 @@ def fig16_windowed(datasets=("phone", "road"), n_edges=6000):
 
 def tab3_throughput(datasets=("phone", "road"), n_edges=20000):
     """Insertion throughput (us/edge, total ms) for GSS/LGS/LSketch, plus
-    the Pallas block-binned insert (interpret mode; structural on CPU)."""
+    the block-binned insert (its XLA key walk off TPU; structural on
+    CPU)."""
     rows = []
     for name in datasets:
         spec, st = _dataset(name, n_edges)

@@ -19,6 +19,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import kernel_bench, paper_tables, roofline
 
     n = 3000 if args.fast else 6000

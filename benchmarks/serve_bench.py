@@ -398,6 +398,8 @@ def main(argv=None):
                     help="Zipf exponent for the skewed-traffic serving "
                          "row (tenant_serve_pooled_zipf_x8)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run_all(quick=args.quick, zipf_a=args.zipf_a)
 
 

@@ -15,10 +15,11 @@ and a dead host-device sync per boundary. This module replaces that with a
      ring slot — so a batch spanning any number of subwindows is one scan;
   4. when the batch sits in a single subwindow (the overwhelmingly common
      case for a real ingest loop) and the sketch uses uniform blocking, the
-     matrix insert is routed to the block-binned Pallas kernel
-     (``kernels/sketch_insert``) — the default fast path on TPU; the scan
-     path doubles as the interpreter/CPU fallback and the only path for
-     skewed blocking or multi-subwindow batches.
+     matrix insert is routed to the block-binned path
+     (``kernels/sketch_insert``: the Pallas key walk on TPU, its XLA
+     lowering elsewhere) — the default on TPU; the scan path is the CPU
+     default and the only path for skewed blocking or multi-subwindow
+     batches.
 
 Host entry point: ``insert_batch(cfg, state, batch, path=...)`` — pads the
 batch to a size bucket (compile-count stays O(log max_batch), padding rows
@@ -42,6 +43,7 @@ from repro.core import hashing as hsh
 from repro.core.lsketch import edge_probes, insert_window_batch, precompute
 from repro.core.queries import PlanesDelta
 from repro.core.types import EMPTY, EdgeBatch, LSketchConfig, LSketchState
+from repro.kernels import use_tpu_kernels
 
 from .window import WindowRing, pad_to_bucket
 
@@ -117,8 +119,7 @@ def _scan_insert(cfg: LSketchConfig, state: LSketchState, probes, le_idx,
 
 def insert_batch_fused_impl(cfg: LSketchConfig, state: LSketchState,
                             batch: EdgeBatch, n_valid: jax.Array,
-                            use_pallas: bool = False,
-                            interpret: bool = True) -> LSketchState:
+                            use_pallas: bool = False) -> LSketchState:
     """One dispatch for a whole time-ordered batch (any #subwindows).
 
     ``n_valid``: traced scalar — rows >= n_valid are padding and are fully
@@ -172,8 +173,7 @@ def insert_batch_fused_impl(cfg: LSketchConfig, state: LSketchState,
 
     def pallas_path(st):
         return matrix_insert_binned(cfg, st, probes, le_idx, w_count,
-                                    plan.slot[0], valid=valid,
-                                    max_bin=B, interpret=interpret)
+                                    plan.slot[0], max_bin=B)
 
     one_segment = _segment_count(
         jnp.where(valid, widx, widx[0])) == jnp.int32(1)
@@ -181,7 +181,7 @@ def insert_batch_fused_impl(cfg: LSketchConfig, state: LSketchState,
 
 
 _insert_batch_fused = functools.partial(
-    jax.jit, static_argnums=(0,), static_argnames=("use_pallas", "interpret"),
+    jax.jit, static_argnums=(0,), static_argnames=("use_pallas",),
     donate_argnums=1)(insert_batch_fused_impl)
 
 
@@ -207,7 +207,6 @@ def _touched_slot_slices(states: LSketchState, slot):
 def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
                               batch: EdgeBatch, n_valid: jax.Array,
                               use_pallas: bool = False,
-                              interpret: bool = True,
                               emit_delta: bool = False):
     """One dispatch for a whole ``[n_shards, B]`` hash-partitioned batch.
 
@@ -296,8 +295,7 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
 
         def pallas_path(st):
             return matrix_insert_binned_sharded(
-                cfg, st, probes, le_idx, w_count, touched,
-                max_bin=B, interpret=interpret)
+                cfg, st, probes, le_idx, w_count, touched, max_bin=B)
 
         out = jax.lax.cond(one_segment_all, pallas_path, scan_path, states)
 
@@ -323,23 +321,28 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
 # host frontends
 # --------------------------------------------------------------------------
 
-def default_path() -> str:
-    """Pallas binned kernel is the default matrix-insert path on TPU;
-    the fused scan is the interpreter/CPU fallback."""
-    return "pallas" if jax.default_backend() == "tpu" else "scan"
+def default_path(cfg: LSketchConfig) -> str:
+    """The binned path (Pallas key walk on TPU) is the default
+    matrix-insert path on TPU for uniform blocking; the fused scan is the
+    default elsewhere and the one path for skewed blocking (the binned
+    walk needs equal tiles)."""
+    if use_tpu_kernels() and cfg.block_bounds is None:
+        return "pallas"
+    return "scan"
 
 
 def resolve_path(cfg: LSketchConfig, path: str = "auto") -> str:
     """Normalize a user-facing path name to "scan" | "pallas" | "chunked".
 
     The one path-selection rule (shared by the single-shard and stacked
-    frontends): "auto" is the backend default; "pallas" silently falls
-    back to "scan" under skewed blocking (the kernel needs uniform tiles).
+    frontends): "auto" is ``default_path(cfg)``; an explicit "pallas" on
+    a skewed-blocking config raises (the kernel needs uniform tiles).
     """
     if path == "auto":
-        path = default_path()
+        path = default_path(cfg)
     if path == "pallas" and cfg.block_bounds is not None:
-        path = "scan"  # kernel requires uniform tiles; silent fallback
+        raise ValueError("path='pallas' needs uniform blocking; skewed "
+                         "block_bounds take path='scan' (or 'auto')")
     if path not in ("scan", "pallas", "chunked"):
         raise ValueError(f"unknown insert path {path!r}")
     return path
@@ -360,10 +363,8 @@ def insert_batch(cfg: LSketchConfig, state: LSketchState, batch: EdgeBatch,
     if path == "chunked":
         return insert_batch_chunked(cfg, state, batch)
     padded = jax.tree.map(pad_to_bucket, batch) if bucket else batch
-    interpret = jax.default_backend() != "tpu"
     return _insert_batch_fused(cfg, state, padded, jnp.int32(n),
-                               use_pallas=path == "pallas",
-                               interpret=interpret)
+                               use_pallas=path == "pallas")
 
 
 def insert_batch_chunked(cfg: LSketchConfig, state: LSketchState,

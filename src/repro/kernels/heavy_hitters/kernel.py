@@ -12,12 +12,14 @@ over the decoded owners is matmul/sort-shaped and stays in XLA
 (``ops.segment_topk``); the per-cell integer decode is the kernelizable
 middle.
 
-Grid = shards; one shard's planes are VMEM-resident per step, exactly
-like ``sketch_query``/``vertex_scan``. ``cell_decode_xla`` is the
-compiled pure-XLA twin (the production CPU route — the pallas path never
-interprets) built on ``hashing.decode_line_vid``, the same shared
-reversibility seam ``reshard``/BFS/host-analytics use; results are
-bit-identical (integer ops only).
+Grid = (shards, row bands): one ``(2, ROWS, d)`` band of one shard's
+key plane per step, so VMEM holds a few MiB at any width (a whole
+``d = 1024`` plane plus its two outputs and the decode temporaries would
+not fit the scoped limit). ``cell_decode_xla`` is the compiled pure-XLA
+twin (the route on every backend but TPU) built on
+``hashing.decode_line_vid``, the same shared reversibility seam
+``reshard``/BFS/host-analytics use; results are bit-identical (integer
+ops only).
 """
 
 from __future__ import annotations
@@ -74,49 +76,59 @@ def _decode_side(lines, idx, f, starts, widths, r: int, F: int):
 
 
 def _decode_body(key_ref, vs_ref, vd_ref, *, starts, widths, r: int, F: int):
-    tl = (0,) * (key_ref.ndim - 3)  # plane tiles trailing (2, d, d)
-    k = key_ref[(*tl, slice(None), slice(None), slice(None))]  # [2, d, d]
+    k = key_ref[0]  # [2, rows, d]: one row band of one shard
     fb = k % jnp.int32(F)
     rest = k // jnp.int32(F)
     fa = rest % jnp.int32(F)
     idx = rest // jnp.int32(F)
     ia = idx // jnp.int32(IDX_RADIX)
     ib = idx % jnp.int32(IDX_RADIX)
-    rows = jax.lax.broadcasted_iota(jnp.int32, k.shape, k.ndim - 2)
-    cols = jax.lax.broadcasted_iota(jnp.int32, k.shape, k.ndim - 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1) \
+        + pl.program_id(1) * k.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, k.shape, 2)
     occ = k != EMPTY
     vs = _decode_side(rows, ia, fa, starts, widths, r, F)
     vd = _decode_side(cols, ib, fb, starts, widths, r, F)
-    sl = (*tl, slice(None), slice(None), slice(None))
-    vs_ref[sl] = jnp.where(occ, vs, EMPTY)
-    vd_ref[sl] = jnp.where(occ, vd, EMPTY)
+    vs_ref[0] = jnp.where(occ, vs, EMPTY)
+    vd_ref[0] = jnp.where(occ, vd, EMPTY)
 
 
-@functools.partial(jax.jit, static_argnames=("n_shards", "starts", "widths",
-                                             "r", "F", "interpret"))
-def cell_decode_kernel_sharded(key_plane, *, n_shards: int, starts, widths,
-                               r: int, F: int, interpret: bool = True):
+def _band_rows(d: int) -> int:
+    """Rows per grid step: a sublane-aligned band that divides d (the
+    whole plane when d has no such divisor — only toy widths)."""
+    for rows in (64, 32, 16, 8):
+        if d % rows == 0:
+            return rows
+    return d
+
+
+@functools.partial(jax.jit, static_argnames=("starts", "widths", "r", "F",
+                                             "interpret"))
+def cell_decode_kernel_sharded(key_plane, *, starts, widths, r: int, F: int,
+                               interpret: bool = False):
     """Decode every cell's (source, destination) packed vids per shard.
 
     key_plane: [n_shards, 2, d, d] twin-leading packed keys (QueryPlanes
     layout). ``starts``/``widths``: the static block partition as tuples.
     Returns (vid_src, vid_dst), each [n_shards, 2, d, d] with EMPTY (-1)
-    on unoccupied cells. Grid ``(n_shards,)`` — one shard's planes
-    VMEM-resident per step.
+    on unoccupied cells. Grid ``(n_shards, d // rows)``; ``interpret`` is
+    for the CPU parity tests only.
     """
-    grid = (n_shards,)
-    plane = pl.BlockSpec((1,) + key_plane.shape[1:], lambda h: (h, 0, 0, 0))
+    S, _, d, _ = key_plane.shape
+    rows = _band_rows(d)
+    band = pl.BlockSpec((1, 2, rows, d), lambda h, i: (h, 0, i, 0))
     vs, vd = pl.pallas_call(
         functools.partial(_decode_body, starts=starts, widths=widths,
                           r=r, F=F),
-        grid=grid,
-        in_specs=[plane],
-        out_specs=[plane, plane],
+        grid=(S, d // rows),
+        in_specs=[band],
+        out_specs=[band, band],
         out_shape=[
             jax.ShapeDtypeStruct(key_plane.shape, jnp.int32),
             jax.ShapeDtypeStruct(key_plane.shape, jnp.int32),
         ],
         interpret=interpret,
+        name="cell_decode",
     )(key_plane)
     return vs, vd
 

@@ -1,13 +1,10 @@
 """Heavy-hitter / top-k analytics over window-reduced QueryPlanes.
 
 Three entry points — ``heavy_vertices_planes`` / ``heavy_edges_planes`` /
-``top_labels_planes`` — with the same path contract as the query kernels:
+``top_labels_planes``:
 
-  * ``interpret=True`` (CPU): pure-XLA decode twin, compiled, never the
-    Pallas interpreter.
-  * ``interpret=False`` (TPU): Pallas cell-decode kernel.
-  * ``_kernel_interpret=True``: force the actual kernel body through the
-    Pallas interpreter (bit-parity tests on CPU).
+  * the cell decode runs on the Pallas kernel on TPU and on its compiled
+    XLA twin elsewhere (``repro.kernels.use_tpu_kernels``);
   * ``axis_name=...``: the same body runs inside ``shard_map`` — decode
     and flatten locally, ``all_gather`` the (identity, weight) rows, run
     the replicated epilogue. Per-identity totals are plain integer sums,
@@ -32,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_tpu_kernels
 from repro.kernels.heavy_hitters.kernel import (
     EMPTY, cell_decode_kernel_sharded, cell_decode_xla)
 
@@ -46,18 +44,15 @@ def _static_blocks(cfg) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
             (cfg.b,) * cfg.n_blocks)
 
 
-def decode_cell_owners(cfg, planes, *, interpret: bool = True,
-                       _kernel_interpret: bool = False):
+def decode_cell_owners(cfg, planes):
     """(vid_src, vid_dst) [S, 2, d, d] — decoded owners of every cell of
     the window-reduced planes, EMPTY (-1) where unoccupied."""
     starts, widths = _static_blocks(cfg)
-    if interpret and not _kernel_interpret:
-        return cell_decode_xla(planes.key, starts=jnp.asarray(starts),
-                               widths=jnp.asarray(widths),
-                               r=cfg.r, F=cfg.F)
-    return cell_decode_kernel_sharded(
-        planes.key, n_shards=planes.key.shape[0], starts=starts,
-        widths=widths, r=cfg.r, F=cfg.F, interpret=interpret)
+    if use_tpu_kernels():
+        return cell_decode_kernel_sharded(planes.key, starts=starts,
+                                          widths=widths, r=cfg.r, F=cfg.F)
+    return cell_decode_xla(planes.key, starts=jnp.asarray(starts),
+                           widths=jnp.asarray(widths), r=cfg.r, F=cfg.F)
 
 
 def _select_topk(vals, k: int):
@@ -154,12 +149,9 @@ def _gathered(arrs, axis_name):
 
 
 def heavy_vertices_planes(cfg, planes, k: int, *, direction: str = "out",
-                          interpret: bool = True,
-                          _kernel_interpret: bool = False,
                           axis_name=None):
     """Top-k (packed vid [k], weight [k]) by windowed out/in weight."""
-    vs, vd = decode_cell_owners(cfg, planes, interpret=interpret,
-                                _kernel_interpret=_kernel_interpret)
+    vs, vd = decode_cell_owners(cfg, planes)
     col = 0 if direction == "out" else 1
     ident, w = _flatten_rows(vs if direction == "out" else vd, planes, col)
     ident, w = _gathered([ident, w], axis_name)
@@ -167,11 +159,9 @@ def heavy_vertices_planes(cfg, planes, k: int, *, direction: str = "out",
     return ids, ws
 
 
-def heavy_edges_planes(cfg, planes, k: int, *, interpret: bool = True,
-                       _kernel_interpret: bool = False, axis_name=None):
+def heavy_edges_planes(cfg, planes, k: int, *, axis_name=None):
     """Top-k edges by windowed weight: (src [k], dst [k], weight [k])."""
-    vs, vd = decode_cell_owners(cfg, planes, interpret=interpret,
-                                _kernel_interpret=_kernel_interpret)
+    vs, vd = decode_cell_owners(cfg, planes)
     src, w = _flatten_rows(vs, planes, 0)
     dst, _ = _flatten_rows(vd, planes, 1)
     src, dst, w = _gathered([src, dst, w], axis_name)
@@ -180,12 +170,10 @@ def heavy_edges_planes(cfg, planes, k: int, *, interpret: bool = True,
 
 
 def top_labels_planes(cfg, planes, k: int, *, direction: str = "out",
-                      interpret: bool = True,
-                      _kernel_interpret: bool = False, axis_name=None):
+                      axis_name=None):
     """Top-k (vertex-label block [k], weight [k]) by windowed out/in
     weight — the decoded vid's block id IS the label block."""
-    vs, vd = decode_cell_owners(cfg, planes, interpret=interpret,
-                                _kernel_interpret=_kernel_interpret)
+    vs, vd = decode_cell_owners(cfg, planes)
     col = 0 if direction == "out" else 1
     vid, w = _flatten_rows(vs if direction == "out" else vd, planes, col)
     # floor division keeps dead rows negative (-1 // span == -1)

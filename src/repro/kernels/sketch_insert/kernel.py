@@ -1,36 +1,41 @@
-"""Pallas kernel: block-binned LSketch batch insertion.
+"""Pallas kernel: the first-fit key walk of block-binned LSketch insertion.
 
-TPU mapping of the paper's hot loop (Algorithm 2, lines 10-23):
+The paper's hot loop (Algorithm 2, lines 10-23) splits into a sequential
+half and a parallel half. First-fit reads and writes only ``key``: each
+edge claims the first of its ``s`` probe cells x 2 twins whose key
+matches or is empty, in stream order. The ``C``/``P`` counters are
+write-only adds at the landing cell. So the kernel walks ``key`` alone
+and returns every edge's landing cell; the caller applies all counter
+weight in one scatter-add (``ops.matrix_insert_binned_sharded``), and
+the ``[d, d, 2, k, c]`` counter planes never enter VMEM.
 
-  * grid = (n_blocks, n_blocks): one grid step per storage block (mA, mB) —
-    the paper's Storage Blocks Division becomes the BlockSpec tiling, so the
-    (b, b) tile of `key`/`C`/`P` lives in VMEM for the whole bin.
-  * the edge bin of a block arrives as padded rows of a (n^2, max_bin, ...)
-    tensor (BlockSpec row-select); padding has weight 0.
-  * within a bin, edges are processed in stream order (`fori_loop`) with the
-    exact sequential first-fit semantics: s sampled probe cells x 2 twin
-    segments, first (key-match | empty) slot wins; failures are flagged for
-    the host-side additional-pool path.
-  * state tensors are updated in place (input_output_aliases).
+TPU mapping (``sketch_insert_walk_kernel``):
 
-``sketch_insert_kernel_sharded`` extends the same body with a leading
-**shard** grid dimension — grid ``(n_shards, n_blocks, n_blocks)`` over
-``[n_shards, ...]``-stacked bins and state planes, so an N-shard ingest is
-one launch instead of N (or a vmapped interpretation). Shards are
-independent by construction (hash-partitioned streams, disjoint state
-tiles), so the extra grid axis carries no cross-program dependence; the
-one kernel body serves both layouts by collapsing whatever leading
-singleton block dims its refs carry.
+  * grid ``(n_shards, n_blocks, n_blocks, n_chunks)``: one storage block
+    (mA, mB) per (shard, i, j). All ``s`` probes of an edge fall inside
+    one block (the paper's Storage Blocks Division), so blocks never
+    share a cell and only the walk inside a block is sequential. The
+    chunk axis streams that block's bin through in ``CHUNK``-edge pieces
+    and is the one sequential ("arbitrary") grid axis.
+  * the block's key tile, laid out ``(2, b // 8, 8, b)``, stays in VMEM
+    across the chunk axis (its block index does not move). A probe reads
+    the (8, b) row group holding its row, a dynamic index on an untiled
+    leading axis, and selects the cell by an iota mask: no dynamic index
+    ever lands on the two tiled minor dims.
+  * the bins travel through SMEM, since the walk needs them as scalars:
+    per edge ``2s + 1`` words (``s`` packed tile-relative cells
+    ``r * b + c``, ``s`` keys, the weight), chunked ``(rows, 128)`` so
+    the SMEM blocks satisfy the (8, 128) block rule. Per-bin fills come
+    in by scalar prefetch, so the walk stops at each bin's last edge.
+  * ``enc`` (per binned edge: 0 = not inserted, else ``1 + (tz * b + r)
+    * b + c``) goes out through SMEM as well.
 
-VMEM budget per grid step (b=128, c=8, int32): key 2*128*128*4 = 128 KiB,
-C plane 128 KiB, P plane 1 MiB, bin arrays O(max_bin*s) — comfortably inside
-the ~16 MiB/core budget; b and max_bin are the tuning knobs (the shard grid
-axis adds no VMEM: each program still sees one shard's one tile).
+VMEM per grid step (b=512): the key tile is 2 MiB, in and out, double
+buffered: 8 MiB against the raised 48 MiB scoped limit.
 
-TPU layout note: the twin axis is kept leading ((2, b, b) tiles) so the
-trailing two dims are lane/sublane-aligned multiples of (8, 128) when b is a
-multiple of 128. Scalar probe reads/writes lower to single-element
-dynamic slices — the same access pattern production paged-KV kernels use.
+``sketch_insert_stream_walk`` is the compiled XLA lowering of the same
+walk, used where the Pallas kernel cannot compile (every backend but
+TPU); tests hold the kernel (in interpret mode) bit-identical to it.
 """
 
 from __future__ import annotations
@@ -40,209 +45,122 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 EMPTY = -1
+CHUNK = 128  # edges per SMEM chunk: (2s + 1) * CHUNK words is a 128-multiple
+VMEM_LIMIT = 48 * 1024 * 1024
 
 
-def _insert_body(rows_ref, cols_ref, keys_ref, le_ref, w_ref,
-                 key_in, c_in, p_in,  # aliased with the out refs below
-                 key_ref, c_ref, p_ref, ok_ref,
-                 *, s: int, max_bin: int):
-    """One storage block: stream the bin through the VMEM tile.
+def _walk_body(counts_ref, bins_ref, key_in, key_ref, enc_ref, *, s: int,
+               b: int, n_blocks: int):
+    """One chunk of one block's bin, walked in stream order."""
+    h, i, j, t = (pl.program_id(a) for a in range(4))
 
-    The state refs are input/output-aliased: ``key_ref``/``c_ref``/``p_ref``
-    hold the input tile on entry and are updated in place.
+    @pl.when(t == 0)
+    def _():  # the out tile starts as the block's input tile
+        key_ref[...] = key_in[...]
 
-    Works for both grid layouts: the per-block bins/tiles may carry extra
-    leading singleton block dims (the shard grid axis); they are collapsed
-    by the index prefixes below.
-    """
-    del key_in, c_in, p_in  # same buffers as the out refs
-    bl3 = (0,) * (rows_ref.ndim - 2)  # bins trailing (max_bin, s)
-    bl2 = (0,) * (w_ref.ndim - 1)  # bins trailing (max_bin,)
-    tl = (0,) * (key_ref.ndim - 3)  # tiles trailing (2, b, b)[, c]
+    fill = jnp.clip(counts_ref[h, i * n_blocks + j] - t * CHUNK, 0, CHUNK)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, b), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, b), 1)
+    width = 2 * s + 1
 
-    def edge(i, _):
-        w = w_ref[(*bl2, i)]
-        # gather the s*2 candidate slots in paper order (probe-major)
-        cand = []
-        for pi in range(s):
-            r = rows_ref[(*bl3, i, pi)]
-            c = cols_ref[(*bl3, i, pi)]
-            kw = keys_ref[(*bl3, i, pi)]
-            for tz in range(2):
-                cur = key_ref[(*tl, tz, r, c)]
-                cand.append((cur == kw) | (cur == EMPTY))
-        okv = jnp.stack(cand)  # [s*2]
-        found = okv.any() & (w > 0)
-        first = jnp.argmax(okv)
-        pi_sel = first // 2
-        tz_sel = first % 2
+    def word(q):
+        return bins_ref[0, 0, 0, q // 128, q % 128]
 
-        # select the winning coordinates (static gather over s alternatives)
-        r_sel = jnp.int32(0)
-        c_sel = jnp.int32(0)
-        k_sel = jnp.int32(0)
-        for pi in range(s):
-            hit = pi_sel == pi
-            r_sel = jnp.where(hit, rows_ref[(*bl3, i, pi)], r_sel)
-            c_sel = jnp.where(hit, cols_ref[(*bl3, i, pi)], c_sel)
-            k_sel = jnp.where(hit, keys_ref[(*bl3, i, pi)], k_sel)
-
-        old_key = jnp.where(tz_sel == 0, key_ref[(*tl, 0, r_sel, c_sel)],
-                            key_ref[(*tl, 1, r_sel, c_sel)])
-        new_key = jnp.where(found, k_sel, old_key)
-        wm = jnp.where(found, w, 0)
-        le = le_ref[(*bl2, i)]
-
-        for tz in range(2):
-            sel = (tz_sel == tz) & found
-            key_ref[(*tl, tz, r_sel, c_sel)] = jnp.where(
-                sel, new_key, key_ref[(*tl, tz, r_sel, c_sel)])
-            c_ref[(*tl, tz, r_sel, c_sel)] = \
-                c_ref[(*tl, tz, r_sel, c_sel)] + jnp.where(sel, wm, 0)
-            p_ref[(*tl, tz, r_sel, c_sel, le)] = \
-                p_ref[(*tl, tz, r_sel, c_sel, le)] + jnp.where(sel, wm, 0)
-        ok_ref[(*bl2, i)] = found
-        return _
-
-    jax.lax.fori_loop(0, max_bin, edge, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("n_blocks", "b", "s", "c",
-                                             "max_bin", "interpret"))
-def sketch_insert_kernel(rows, cols, keys, le, w, key, C_plane, P_plane,
-                         *, n_blocks: int, b: int, s: int, c: int,
-                         max_bin: int, interpret: bool = True):
-    """rows/cols: [n^2, max_bin, s] block-relative probe coords;
-    keys: [n^2, max_bin, s]; le/w: [n^2, max_bin];
-    key/C_plane: [2, d, d]; P_plane: [2, d, d, c]  (current-slot planes).
-
-    Returns (key, C_plane, P_plane, inserted_flags[n^2, max_bin]).
-    """
-    n2 = n_blocks * n_blocks
-    grid = (n_blocks, n_blocks)
-
-    bin_spec3 = pl.BlockSpec((1, max_bin, s), lambda i, j: (i * n_blocks + j, 0, 0))
-    bin_spec2 = pl.BlockSpec((1, max_bin), lambda i, j: (i * n_blocks + j, 0))
-    tile = pl.BlockSpec((2, b, b), lambda i, j: (0, i, j))
-    tile_p = pl.BlockSpec((2, b, b, c), lambda i, j: (0, i, j, 0))
-
-    out = pl.pallas_call(
-        functools.partial(_insert_body, s=s, max_bin=max_bin),
-        grid=grid,
-        in_specs=[bin_spec3, bin_spec3, bin_spec3, bin_spec2, bin_spec2,
-                  tile, tile, tile_p],
-        out_specs=[tile, tile, tile_p, bin_spec2],
-        out_shape=[
-            jax.ShapeDtypeStruct(key.shape, key.dtype),
-            jax.ShapeDtypeStruct(C_plane.shape, C_plane.dtype),
-            jax.ShapeDtypeStruct(P_plane.shape, P_plane.dtype),
-            jax.ShapeDtypeStruct((n2, max_bin), jnp.bool_),
-        ],
-        input_output_aliases={5: 0, 6: 1, 7: 2},
-        interpret=interpret,
-    )(rows, cols, keys, le, w, key, C_plane, P_plane)
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("n_shards", "n_blocks", "b",
-                                             "s", "c", "max_bin"))
-def sketch_insert_tiles_xla(rows, cols, keys, le, w, key, C_plane, P_plane,
-                            limit=None, *, n_shards: int, n_blocks: int,
-                            b: int, s: int, c: int, max_bin: int):
-    """Pure-XLA twin of ``sketch_insert_kernel_sharded`` — same I/O
-    contract, bit-identical results: the executable model of the Pallas
-    kernel (tests assert kernel == twin on identical binned inputs).
-
-    The kernel's grid axes are embarrassingly parallel (all ``s`` probes of
-    an edge live inside one (row-block, col-block) tile, so bins never
-    share a matrix cell); only the walk *within* a bin is sequential. This
-    twin exploits exactly that: one ``lax.while_loop`` over bin positions
-    whose body processes **one edge of every (shard, block) bin
-    simultaneously** (vectorized gathers/scatters over the
-    ``n_shards * n_blocks^2`` tile axis). The production CPU path goes one
-    step further (``sketch_insert_stream_walk`` below: no materialized
-    bins, counters out of the loop); this twin stays shaped exactly like
-    the kernel so the two can be diffed tensor-for-tensor.
-
-    ``limit`` (traced scalar, optional): the largest actual bin fill across
-    all bins. Positions >= the fill of every bin are provable no-ops (the
-    binning pads with weight 0, and zero weight neither claims nor adds),
-    so the walk stops there instead of grinding through ``max_bin``.
-    """
-    S, n2 = n_shards, n_blocks * n_blocks
-    NB = S * n2
-    nb_idx = jnp.arange(NB, dtype=jnp.int32)
-    limit = jnp.int32(max_bin) if limit is None else \
-        jnp.minimum(jnp.asarray(limit, jnp.int32), max_bin)
-
-    def to_tiles(plane):  # [S, 2, d, d(, c)] -> [NB, 2, b, b(, c)]
-        extra = plane.shape[4:]
-        x = plane.reshape((S, 2, n_blocks, b, n_blocks, b) + extra)
-        x = jnp.moveaxis(x, (2, 4), (1, 2))
-        return x.reshape((NB, 2, b, b) + extra)
-
-    def from_tiles(tiles):  # inverse of to_tiles
-        extra = tiles.shape[4:]
-        x = tiles.reshape((S, n_blocks, n_blocks, 2, b, b) + extra)
-        x = jnp.moveaxis(x, (1, 2), (2, 4))
-        d = n_blocks * b
-        return x.reshape((S, 2, d, d) + extra)
-
-    def to_stream(x):  # [S, n2, max_bin, ...] -> [max_bin, NB, ...]
-        flat = x.reshape((NB, max_bin) + x.shape[3:])
-        return jnp.moveaxis(flat, 1, 0)
-
-    xs = tuple(to_stream(v) for v in (rows, cols, keys, le, w))
-
-    def body(state):
-        t, key_t, C_t, P_t, flags = state
-        r, cc, kk, le_t, w_t = (x[t] for x in xs)  # [NB, s] x3, [NB], [NB]
+    def edge(e, carry):
+        base = e * width
+        found = jnp.bool_(False)
+        tz_sel = jnp.int32(0)
+        cell_sel = jnp.int32(0)
+        key_sel = jnp.int32(0)
         # the s*2 candidates in paper order (probe-major, twin-minor)
-        cur = key_t[nb_idx[:, None, None], jnp.arange(2)[None, None, :],
-                    r[:, :, None], cc[:, :, None]]  # [NB, s, 2]
-        ok = ((cur == kk[:, :, None]) | (cur == EMPTY)).reshape(NB, -1)
-        found = ok.any(axis=1) & (w_t > 0)
-        first = jnp.argmax(ok, axis=1)
-        pi, tz = first // 2, first % 2
-        take = lambda a: jnp.take_along_axis(a, pi[:, None], axis=1)[:, 0]
-        r_sel, c_sel, k_sel = take(r), take(cc), take(kk)
-        old = key_t[nb_idx, tz, r_sel, c_sel]
-        wm = jnp.where(found, w_t, 0)
-        key_t = key_t.at[nb_idx, tz, r_sel, c_sel].set(
-            jnp.where(found, k_sel, old))
-        C_t = C_t.at[nb_idx, tz, r_sel, c_sel].add(wm)
-        P_t = P_t.at[nb_idx, tz, r_sel, c_sel, le_t].add(wm)
-        return t + 1, key_t, C_t, P_t, flags.at[t].set(found)
+        for p in range(s):
+            cell = word(base + p)
+            kw = word(base + s + p)
+            r, c = cell // b, cell % b
+            at = (sub == r % 8) & (lane == c)
+            for tz in range(2):
+                cur = jnp.sum(jnp.where(at, key_ref[0, tz, r // 8], 0))
+                ok = ((cur == kw) | (cur == EMPTY)) & ~found
+                tz_sel = jnp.where(ok, tz, tz_sel)
+                cell_sel = jnp.where(ok, cell, cell_sel)
+                key_sel = jnp.where(ok, kw, key_sel)
+                found = found | ok
+        found = found & (word(base + 2 * s) > 0)
+        r, c = cell_sel // b, cell_sel % b
 
-    state = (jnp.int32(0), to_tiles(key), to_tiles(C_plane),
-             to_tiles(P_plane), jnp.zeros((max_bin, NB), jnp.bool_))
-    _, key_t, C_t, P_t, flags = jax.lax.while_loop(
-        lambda st: st[0] < limit, body, state)
-    flags = jnp.moveaxis(flags, 0, 1).reshape(S, n2, max_bin)
-    return from_tiles(key_t), from_tiles(C_t), from_tiles(P_t), flags
+        @pl.when(found)
+        def _():
+            rows8 = key_ref[0, tz_sel, r // 8]
+            key_ref[0, tz_sel, r // 8] = jnp.where(
+                (sub == r % 8) & (lane == c), key_sel, rows8)
+
+        enc_ref[0, 0, 0, 0, e] = jnp.where(found,
+                                           1 + tz_sel * (b * b) + cell_sel, 0)
+        return carry
+
+    jax.lax.fori_loop(0, fill, edge, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_blocks", "b", "s",
+                                             "interpret"))
+def sketch_insert_walk_kernel(bins, counts, key, *, n_blocks: int, b: int,
+                              s: int, interpret: bool = False):
+    """First-fit key walk over every shard's every storage block.
+
+    bins: int32 ``[S, n^2, max_bin, 2s + 1]`` per binned edge: ``s``
+    tile-relative cells ``r * b + c``, ``s`` keys, the weight (zero-padded
+    past each bin's fill); ``max_bin`` a multiple of ``CHUNK``.
+    counts: int32 ``[S, n^2]`` bin fills. key: ``[S, 2, d, d]``
+    twin-leading keys (updated in place under donation).
+
+    Returns ``(new_key [S, 2, d, d], enc [S, n^2, max_bin])``.
+    """
+    S, n2, max_bin, width = bins.shape
+    if width != 2 * s + 1 or max_bin % CHUNK:
+        raise ValueError("bins must be [S, n^2, k * CHUNK, 2s + 1]")
+    nch = max_bin // CHUNK
+    d = n_blocks * b
+    rows = CHUNK * width // 128
+
+    tile = pl.BlockSpec((1, 2, b // 8, 8, b),
+                        lambda h, i, j, t, cnt: (h, 0, i, 0, j))
+    chunk = lambda h, i, j, t, cnt: (h, i * n_blocks + j, t, 0, 0)
+    bin_spec = pl.BlockSpec((1, 1, 1, rows, 128), chunk,
+                            memory_space=pltpu.SMEM)
+    enc_spec = pl.BlockSpec((1, 1, 1, 1, CHUNK), chunk,
+                            memory_space=pltpu.SMEM)
+    key5 = key.reshape(S, 2, d // 8, 8, d)
+    new_key, enc = pl.pallas_call(
+        functools.partial(_walk_body, s=s, b=b, n_blocks=n_blocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, n_blocks, n_blocks, nch),
+            in_specs=[bin_spec, tile], out_specs=[tile, enc_spec]),
+        out_shape=[jax.ShapeDtypeStruct(key5.shape, key5.dtype),
+                   jax.ShapeDtypeStruct((S, n2, nch, 1, CHUNK), jnp.int32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="sketch_insert_walk",
+    )(counts, bins.reshape(S, n2, nch, rows, 128), key5)
+    return new_key.reshape(S, 2, d, d), enc.reshape(S, n2, max_bin)
 
 
 def sketch_insert_stream_walk(rows, cols, keys, w, order, offs, counts,
                               key, *, n_shards: int, n_blocks: int, b: int,
                               max_bin: int | None = None):
-    """The sequential half of the binned insert, alone: walk the key tiles
-    in bin order and *collect* each edge's landing cell instead of
-    updating counter planes.
+    """XLA lowering of ``sketch_insert_walk_kernel``: walk the key tiles
+    in bin order and *collect* each edge's landing cell.
 
-    Two observations make this the fast XLA lowering of the binned
-    program (``pallas_call`` on CPU only interprets):
-
-      * the first-fit walk reads and writes only ``key`` — the ``C``/``P``
-        counters are write-only scatter-adds, so they need not ride
-        through the sequential loop at all; the caller applies all counter
-        weight in one vectorized scatter-add into the full stacked state
-        (no ring-slot plane gather, no tile reshape, no write-back copy);
-      * bins never need materializing — the loop reads edge ``t`` of every
-        bin straight out of the bin-sorted stream (a gather at
-        ``offs + t``), so the ``[n2, max_bin, ...]`` padding tensors the
-        hardware kernel's BlockSpecs require are never built.
+    One step of the ``while_loop`` processes edge ``t`` of every
+    (shard, block) bin at once, read straight out of the bin-sorted
+    stream (a gather at ``offs + t``), so the ``[n2, max_bin, ...]`` bin
+    tensors the kernel's BlockSpecs need are never built here.
 
     Inputs: ``rows``/``cols`` ([S, B, s], **tile-relative** probe coords,
     stream order), ``keys`` [S, B, s], ``w`` [S, B] (weights already
@@ -256,8 +174,8 @@ def sketch_insert_stream_walk(rows, cols, keys, w, order, offs, counts,
     landing cell, packed. Walk length is ``min(max(counts), max_bin)``:
     the true largest bin fill, not the padded batch length — and capped
     at ``max_bin`` so a tuned bin capacity drops each bin's overflow
-    edges to the pool exactly like the hardware kernel's truncated bins
-    (an un-walked edge keeps ``enc == 0``). Traced (not jitted) —
+    edges to the pool exactly like the kernel's truncated bins (an
+    un-walked edge keeps ``enc == 0``). Traced (not jitted) —
     compose inside a jitted caller.
     """
     S, n2 = n_shards, n_blocks * n_blocks
@@ -316,51 +234,3 @@ def sketch_insert_stream_walk(rows, cols, keys, w, order, offs, counts,
         jnp.arange(S, dtype=jnp.int32)[:, None], order].set(
             enc_s.reshape(S, B))
     return new_key, enc
-
-
-@functools.partial(jax.jit, static_argnames=("n_shards", "n_blocks", "b",
-                                             "s", "c", "max_bin",
-                                             "interpret"))
-def sketch_insert_kernel_sharded(rows, cols, keys, le, w, key, C_plane,
-                                 P_plane, *, n_shards: int, n_blocks: int,
-                                 b: int, s: int, c: int, max_bin: int,
-                                 interpret: bool = True):
-    """Shard-axis variant: one launch over every shard's every block.
-
-    rows/cols/keys: [n_shards, n^2, max_bin, s]; le/w: [n_shards, n^2,
-    max_bin]; key/C_plane: [n_shards, 2, d, d]; P_plane: [n_shards, 2, d,
-    d, c] (each shard's current-slot planes, gathered at its own ring
-    slot by the caller).
-
-    Returns (key, C_plane, P_plane, inserted_flags[n_shards, n^2,
-    max_bin]). Grid ``(n_shards, n_blocks, n_blocks)`` — the shard axis is
-    the outermost (slowest) grid dimension, so each shard's tiles stream
-    through VMEM contiguously, exactly like n_shards back-to-back launches
-    of ``sketch_insert_kernel`` but with one dispatch and one pipeline.
-    """
-    n2 = n_blocks * n_blocks
-    grid = (n_shards, n_blocks, n_blocks)
-
-    bin_spec4 = pl.BlockSpec((1, 1, max_bin, s),
-                             lambda h, i, j: (h, i * n_blocks + j, 0, 0))
-    bin_spec3 = pl.BlockSpec((1, 1, max_bin),
-                             lambda h, i, j: (h, i * n_blocks + j, 0))
-    tile = pl.BlockSpec((1, 2, b, b), lambda h, i, j: (h, 0, i, j))
-    tile_p = pl.BlockSpec((1, 2, b, b, c), lambda h, i, j: (h, 0, i, j, 0))
-
-    out = pl.pallas_call(
-        functools.partial(_insert_body, s=s, max_bin=max_bin),
-        grid=grid,
-        in_specs=[bin_spec4, bin_spec4, bin_spec4, bin_spec3, bin_spec3,
-                  tile, tile, tile_p],
-        out_specs=[tile, tile, tile_p, bin_spec3],
-        out_shape=[
-            jax.ShapeDtypeStruct(key.shape, key.dtype),
-            jax.ShapeDtypeStruct(C_plane.shape, C_plane.dtype),
-            jax.ShapeDtypeStruct(P_plane.shape, P_plane.dtype),
-            jax.ShapeDtypeStruct((n_shards, n2, max_bin), jnp.bool_),
-        ],
-        input_output_aliases={5: 0, 6: 1, 7: 2},
-        interpret=interpret,
-    )(rows, cols, keys, le, w, key, C_plane, P_plane)
-    return out

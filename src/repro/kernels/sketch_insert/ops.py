@@ -1,26 +1,26 @@
-"""Public wrappers for the block-binned Pallas insertion kernel.
+"""Public wrappers for the block-binned insertion kernel.
 
 Pipeline (DESIGN.md §2 "binned batch insertion"):
   1. advance the sliding window (``engine.WindowRing`` claim/zero — or the
      fused segment plan when called from ``engine.insert``);
   2. vectorized addressing: probes, keys, block ids for the whole batch;
   3. stable binning by destination block (order within a block == stream
-    order, so first-fit semantics match the sequential algorithm exactly);
-  4. Pallas kernel over the (n x n) block grid, current-slot planes in VMEM;
-  5. host-side additional-pool pass for the (rare) all-probes-occupied edges,
-    in original stream order.
+     order, so first-fit semantics match the sequential algorithm exactly);
+  4. the first-fit key walk per block: the Pallas kernel on TPU, its
+     compiled XLA lowering elsewhere (``repro.kernels.use_tpu_kernels``);
+  5. one scatter-add of every landed edge's weight into ``C``/``P``;
+  6. additional-pool pass for the (rare) all-probes-occupied edges, in
+     original stream order.
 
-``matrix_insert_binned`` is the composable middle: it takes pre-addressed
-probes plus the (single) target ring slot and is what the engine's fused
-single-dispatch path routes through; ``matrix_insert_binned_sharded`` is
-its shard-axis twin — the same binning per shard, one
-``(n_shards, n_blocks, n_blocks)``-grid launch, a vmapped pool pass — used
-by the engine's stacked insert for the ``repro.sketch`` handle layer;
-``insert_window_batch_pallas`` is the standalone per-subwindow drop-in
-kept for tests and direct use.
+``matrix_insert_binned_sharded`` is the composable middle: pre-addressed
+probes plus each shard's target ring slot, over a ``[n_shards, ...]``
+stack; the engine's stacked insert (the ``repro.sketch`` handle layer)
+routes through it. ``matrix_insert_binned`` is its one-shard case and
+``insert_window_batch_pallas`` the standalone per-subwindow drop-in kept
+for tests and direct use.
 
-Restrictions: uniform blocking only (equal tiles — skewed blocking falls
-back to `repro.core.insert_window_batch`, the fori-loop path).
+Restrictions: uniform blocking only (equal tiles — skewed blocking takes
+the engine's scan path, ``engine.insert.resolve_path``).
 """
 
 from __future__ import annotations
@@ -35,8 +35,9 @@ from repro.core.lsketch import (EdgeProbes, advance_window, edge_probes,
                                 precompute)
 from repro.core.types import EdgeBatch, LSketchConfig, LSketchState
 
-from .kernel import (sketch_insert_kernel, sketch_insert_kernel_sharded,
-                     sketch_insert_stream_walk)
+from repro.kernels import use_tpu_kernels
+
+from .kernel import CHUNK, sketch_insert_stream_walk, sketch_insert_walk_kernel
 
 
 def _pool_pass(cfg: LSketchConfig, state: LSketchState, slot, probes, le_idx,
@@ -107,102 +108,77 @@ def _bin_plan(cfg: LSketchConfig, probes: EdgeProbes, weight):
     return bid0, bid, order, counts, offs
 
 
-def _bin_batch(cfg: LSketchConfig, probes: EdgeProbes, le_idx, weight,
-               max_bin: int):
-    """Stable binning of one shard's pre-addressed batch by destination
-    block (uniform tiles: block = row // b). Returns the binned tensors
-    plus the (order, bid_s, pos, ok_pos) permutation needed to un-bin the
-    kernel's inserted flags back to stream order, plus per-bin fill
-    counts. Batch-rank-agnostic in the sense that it vmaps cleanly over a
-    leading shard axis."""
+def _key_walk_xla(cfg: LSketchConfig, probes: EdgeProbes, weight, key_t,
+                  max_bin: int):
+    """First-fit key walk, compiled XLA lowering. ``probes``/``weight``
+    carry a leading shard axis; ``key_t`` is ``[S, 2, d, d]``. Returns
+    ``(new_key_t, enc [S, B] in stream order, bid0 [S, B])``."""
     n, b = cfg.n_blocks, cfg.b
-    B = probes.rows.shape[0]
-    _, bid, order, counts, offs = _bin_plan(cfg, probes, weight)
-    bid_s = bid[order]
-    pos = jnp.arange(B, dtype=jnp.int32) - \
-        offs[jnp.minimum(bid_s, n * n - 1)].astype(jnp.int32)
-    ok_pos = (pos < max_bin) & (bid_s < jnp.int32(n * n))
-
-    def to_bins(x, fill=0):
-        shape = (n * n, max_bin) + x.shape[1:]
-        out = jnp.full(shape, fill, x.dtype)
-        return out.at[bid_s, pos].set(x[order], mode="drop")
-
-    rows_b = to_bins(probes.rows % jnp.int32(b))
-    cols_b = to_bins(probes.cols % jnp.int32(b))
-    keys_b = to_bins(probes.keys)
-    le_b = to_bins(le_idx)
-    w_b = to_bins(weight)
-    return (rows_b, cols_b, keys_b, le_b, w_b), (order, bid_s, pos, ok_pos), \
-        counts
+    bid0, _, order, counts, offs = jax.vmap(
+        lambda p, w: _bin_plan(cfg, p, w))(probes, weight)
+    new_key_t, enc = sketch_insert_stream_walk(
+        probes.rows % jnp.int32(b), probes.cols % jnp.int32(b),
+        probes.keys, weight, order, offs, counts, key_t,
+        n_shards=weight.shape[0], n_blocks=n, b=b, max_bin=max_bin)
+    return new_key_t, enc, bid0
 
 
-def _unbin_flags(flags, order, bid_s, pos, ok_pos, B):
-    """Inserted flags [n^2, max_bin] -> stream order [B]."""
-    flags_sorted = flags[bid_s, pos] & ok_pos
-    return jnp.zeros((B,), jnp.bool_).at[order].set(flags_sorted)
+def _key_walk_pallas(cfg: LSketchConfig, probes: EdgeProbes, weight, key_t,
+                     max_bin: int, interpret: bool = False):
+    """First-fit key walk on the Pallas kernel: the same contract as
+    ``_key_walk_xla``, bit-identical. Materializes the bins the kernel's
+    BlockSpecs read (``[S, n^2, max_bin, 2s + 1]``, one SMEM row per
+    binned edge) and un-bins ``enc`` back to stream order.
+    ``interpret`` is for the CPU parity tests only."""
+    n, b, s = cfg.n_blocks, cfg.b, cfg.s
+    n2 = n * n
+    cap = -(-max_bin // CHUNK) * CHUNK
+
+    def bin_one(p, w):
+        bid0, bid, order, counts, offs = _bin_plan(cfg, p, w)
+        bid_s = bid[order]
+        pos = jnp.arange(w.shape[0], dtype=jnp.int32) \
+            - offs[jnp.minimum(bid_s, n2 - 1)].astype(jnp.int32)
+        row = jnp.concatenate([(p.rows % b) * b + p.cols % b, p.keys,
+                               w[:, None]], axis=1)  # [B, 2s + 1]
+        bins = jnp.zeros((n2, cap, 2 * s + 1), jnp.int32).at[
+            bid_s, pos].set(row[order], mode="drop")
+        fill = jnp.minimum(counts, max_bin).astype(jnp.int32)
+        return bins, fill, (bid0, order, bid_s, pos)
+
+    bins, fill, (bid0, order, bid_s, pos) = jax.vmap(bin_one)(probes, weight)
+    new_key_t, enc_b = sketch_insert_walk_kernel(
+        bins, fill, key_t, n_blocks=n, b=b, s=s, interpret=interpret)
+
+    def unbin(e, o, bs, ps):
+        walked = (bs < n2) & (ps < max_bin)
+        got = e[jnp.minimum(bs, n2 - 1), jnp.clip(ps, 0, cap - 1)]
+        return jnp.zeros_like(o).at[o].set(jnp.where(walked, got, 0))
+
+    return new_key_t, jax.vmap(unbin)(enc_b, order, bid_s, pos), bid0
 
 
 def matrix_insert_binned(cfg: LSketchConfig, state: LSketchState,
                          probes: EdgeProbes, le_idx, weight, slot,
-                         valid=None, max_bin: int | None = None,
-                         interpret: bool = True) -> LSketchState:
-    """Block-binned insertion of a pre-addressed batch into ring ``slot``.
+                         max_bin: int | None = None) -> LSketchState:
+    """Block-binned insertion of one shard's pre-addressed batch into ring
+    ``slot``: the one-shard case of ``matrix_insert_binned_sharded``.
 
     Traced (not jitted) — compose inside a jitted caller. ``weight`` must
     already carry the window-liveness mask (zeros insert nothing and claim
     nothing); ``slot`` is the (traced) ring slot shared by the whole batch.
     """
-    if cfg.block_bounds is not None:
-        raise ValueError("Pallas path supports uniform blocking only")
-    n, b = cfg.n_blocks, cfg.b
-    B = probes.rows.shape[0]
-    max_bin = B if max_bin is None else max_bin
-    del valid  # zero-weight rows (padding or expired) are inert already
-
-    if interpret:  # bin-parallel XLA lowering (1-shard stack): the CPU path
-        lifted = jax.tree.map(lambda x: x[None], state)
-        out = matrix_insert_binned_sharded(
-            cfg, lifted, jax.tree.map(lambda x: x[None], probes),
-            le_idx[None], weight[None], slot[None], max_bin=max_bin,
-            interpret=True)
-        return jax.tree.map(lambda x: x[0], out)
-
-    (rows_b, cols_b, keys_b, le_b, w_b), (order, bid_s, pos, ok_pos), \
-        counts = _bin_batch(cfg, probes, le_idx, weight, max_bin)
-
-    # --- current-slot planes, twin-leading layout ---
-    key_t = jnp.moveaxis(state.key, 2, 0)  # [2, d, d]
-    C_t = jnp.moveaxis(state.C[..., slot], 2, 0)  # [2, d, d]
-    P_t = jnp.moveaxis(state.P[..., slot, :], 2, 0)  # [2, d, d, c]
-
-    key_t, C_t, P_t, flags = sketch_insert_kernel(
-        rows_b, cols_b, keys_b, le_b, w_b, key_t, C_t, P_t,
-        n_blocks=n, b=b, s=cfg.s, c=cfg.c, max_bin=max_bin,
-        interpret=False)
-
-    new_key = jnp.moveaxis(key_t, 0, 2)
-    new_C = state.C.at[..., slot].set(jnp.moveaxis(C_t, 0, 2))
-    new_P = state.P.at[..., slot, :].set(jnp.moveaxis(P_t, 0, 2))
-    state = LSketchState(key=new_key, C=new_C, P=new_P,
-                         pool_key=state.pool_key, pool_C=state.pool_C,
-                         pool_P=state.pool_P, pool_lost=state.pool_lost,
-                         slot_widx=state.slot_widx, cur_widx=state.cur_widx)
-
-    # --- un-bin the inserted flags back to stream order; pool pass ---
-    inserted = _unbin_flags(flags, order, bid_s, pos, ok_pos, B)
-    failed = (~inserted) & (weight > 0)
-    return _pool_pass(cfg, state, slot, probes, le_idx, weight, failed)
+    lift = lambda x: x[None]
+    out = matrix_insert_binned_sharded(
+        cfg, jax.tree.map(lift, state), jax.tree.map(lift, probes),
+        le_idx[None], weight[None], jnp.asarray(slot)[None], max_bin=max_bin)
+    return jax.tree.map(lambda x: x[0], out)
 
 
 def matrix_insert_binned_sharded(cfg: LSketchConfig, state: LSketchState,
                                  probes: EdgeProbes, le_idx, weight, slot,
-                                 max_bin: int | None = None,
-                                 interpret: bool = True,
-                                 _kernel_interpret: bool = False
-                                 ) -> LSketchState:
-    """Shard-axis twin of ``matrix_insert_binned``: one Pallas launch over
-    the whole ``[n_shards, ...]`` stack.
+                                 max_bin: int | None = None) -> LSketchState:
+    """Block-binned insertion over a whole ``[n_shards, ...]`` stack.
 
     ``state`` carries a leading ``[n_shards]`` axis on every leaf; probe
     tensors are ``[n_shards, B, s]``, ``le_idx``/``weight`` are
@@ -213,63 +189,33 @@ def matrix_insert_binned_sharded(cfg: LSketchConfig, state: LSketchState,
     shard stays a strict no-op). Traced (not jitted) — compose inside a
     jitted caller.
 
-    ``_kernel_interpret`` (tests only): with ``interpret=False``, run the
-    hardware-kernel branch but in Pallas interpret mode — the only way to
-    exercise that branch end-to-end on CPU (lowering-parity tests).
+    The key walk runs on the Pallas kernel on TPU and on its compiled XLA
+    lowering elsewhere; either way the counters (write-only in the walk)
+    land in one scatter-add on the full stacked ``C``/``P``, with no
+    per-slot plane gather or write-back.
     """
     if cfg.block_bounds is not None:
-        raise ValueError("Pallas path supports uniform blocking only")
+        raise ValueError("the binned insert supports uniform blocking only")
     S, B = probes.rows.shape[:2]
     max_bin = B if max_bin is None else max_bin
-
     n, b = cfg.n_blocks, cfg.b
-    key_t = jnp.moveaxis(state.key, 3, 1)  # [S, 2, d, d]
 
-    if interpret:
-        # XLA lowering (sketch_insert_stream_walk): no bin tensors, the
-        # walk reads the bin-sorted stream directly; the counters
-        # (write-only in the walk) land in one scatter-add on the full
-        # stacked C/P — no per-slot plane gather or write-back.
-        bid0, _, order, counts, offs = jax.vmap(
-            lambda p, w: _bin_plan(cfg, p, w))(probes, weight)
-        new_key_t, enc = sketch_insert_stream_walk(
-            probes.rows % jnp.int32(b), probes.cols % jnp.int32(b),
-            probes.keys, weight, order, offs, counts, key_t,
-            n_shards=S, n_blocks=n, b=b, max_bin=max_bin)
-        inserted = enc > 0  # [S, B], stream order
-        v = jnp.maximum(enc - 1, 0)
-        tzs = v // (b * b)
-        rs = (bid0 // jnp.int32(n)) * jnp.int32(b) + (v // b) % b
-        cs = (bid0 % jnp.int32(n)) * jnp.int32(b) + v % b
-        wm = jnp.where(inserted, weight, 0)
-        s_idx = jnp.arange(S, dtype=jnp.int32)[:, None]
-        slot_b = slot[:, None]
-        new_C = state.C.at[s_idx, rs, cs, tzs, slot_b].add(wm)
-        new_P = state.P.at[s_idx, rs, cs, tzs, slot_b, le_idx].add(wm)
-    else:
-        # hardware kernel: materialized bins (BlockSpec row-select) over
-        # per-shard current-slot planes, twin-leading layout
-        bins, unbin, _ = jax.vmap(
-            lambda p, le, w: _bin_batch(cfg, p, le, w, max_bin))(
-                probes, le_idx, weight)
-        rows_b, cols_b, keys_b, le_b, w_b = bins
-        C_t = jax.vmap(lambda Cs, sl: jnp.moveaxis(Cs[..., sl], 2, 0))(
-            state.C, slot)  # [S, 2, d, d]
-        P_t = jax.vmap(lambda Ps, sl: jnp.moveaxis(Ps[..., sl, :], 2, 0))(
-            state.P, slot)  # [S, 2, d, d, c]
-        new_key_t, C_t, P_t, flags = sketch_insert_kernel_sharded(
-            rows_b, cols_b, keys_b, le_b, w_b, key_t, C_t, P_t,
-            n_shards=S, n_blocks=cfg.n_blocks, b=cfg.b, s=cfg.s, c=cfg.c,
-            max_bin=max_bin, interpret=_kernel_interpret)
-        new_C = jax.vmap(lambda Cs, Ct, sl: Cs.at[..., sl].set(
-            jnp.moveaxis(Ct, 0, 2)))(state.C, C_t, slot)
-        new_P = jax.vmap(lambda Ps, Pt, sl: Ps.at[..., sl, :].set(
-            jnp.moveaxis(Pt, 0, 2)))(state.P, P_t, slot)
-        inserted = jax.vmap(
-            lambda fl, ub: _unbin_flags(fl, *ub, B))(flags, unbin)
-
-    state = LSketchState(key=jnp.moveaxis(new_key_t, 1, 3), C=new_C,
-                         P=new_P, pool_key=state.pool_key,
+    walk = _key_walk_pallas if use_tpu_kernels() else _key_walk_xla
+    new_key_t, enc, bid0 = walk(cfg, probes, weight,
+                                jnp.moveaxis(state.key, 3, 1), max_bin)
+    inserted = enc > 0  # [S, B], stream order
+    v = jnp.maximum(enc - 1, 0)
+    tzs = v // (b * b)
+    rs = (bid0 // jnp.int32(n)) * jnp.int32(b) + (v // b) % b
+    cs = (bid0 % jnp.int32(n)) * jnp.int32(b) + v % b
+    wm = jnp.where(inserted, weight, 0)
+    s_idx = jnp.arange(S, dtype=jnp.int32)[:, None]
+    slot_b = slot[:, None]
+    state = LSketchState(key=jnp.moveaxis(new_key_t, 1, 3),
+                         C=state.C.at[s_idx, rs, cs, tzs, slot_b].add(wm),
+                         P=state.P.at[s_idx, rs, cs, tzs, slot_b,
+                                      le_idx].add(wm),
+                         pool_key=state.pool_key,
                          pool_C=state.pool_C, pool_P=state.pool_P,
                          pool_lost=state.pool_lost,
                          slot_widx=state.slot_widx, cur_widx=state.cur_widx)
@@ -281,12 +227,11 @@ def matrix_insert_binned_sharded(cfg: LSketchConfig, state: LSketchState,
     )(state, slot, probes, le_idx, weight, failed)
 
 
-@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("max_bin", "interpret"),
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("max_bin",),
                    donate_argnums=1)
 def insert_window_batch_pallas(cfg: LSketchConfig, state: LSketchState,
                                batch: EdgeBatch, widx,
-                               max_bin: int | None = None,
-                               interpret: bool = True) -> LSketchState:
+                               max_bin: int | None = None) -> LSketchState:
     """Drop-in replacement for ``repro.core.insert_window_batch``."""
     pa = precompute(cfg, batch.src, batch.src_label)
     pb = precompute(cfg, batch.dst, batch.dst_label)
@@ -295,4 +240,4 @@ def insert_window_batch_pallas(cfg: LSketchConfig, state: LSketchState,
     state, slot, live = advance_window(cfg, state, jnp.asarray(widx, jnp.int32))
     weight = batch.weight.astype(state.C.dtype) * live.astype(state.C.dtype)
     return matrix_insert_binned(cfg, state, probes, le_idx, weight, slot,
-                                max_bin=max_bin, interpret=interpret)
+                                max_bin=max_bin)

@@ -1,13 +1,15 @@
-"""Wrappers for the batched edge-query kernel: planes walk + pool path.
+"""Batched edge queries on window-reduced planes: probe walk + pool path.
 
-``edge_query_planes`` is the composable middle of the "pallas" query path
-(DESIGN.md §8): it takes pre-reduced ``QueryPlanes`` (shard-stacked) plus
-a query batch and answers every query against every shard — the matrix
-probe walk on the kernel (TPU) or its compiled XLA lowering (everywhere
-else; the pallas path never interprets), plus the vectorized pool lookup
-for all-occupied-mismatch queries. ``repro.sketch.query`` routes through
-it; ``edge_query_pallas`` is the standalone single-sketch drop-in kept
-for tests and direct use.
+``edge_query_planes`` is the composable middle of the "pallas" (planes)
+query path (DESIGN.md §8): it takes pre-reduced ``QueryPlanes``
+(shard-stacked) plus a query batch and answers every query against every
+shard — the matrix probe walk (``sketch_query_xla``: ``s * 2`` gathered
+candidates per query and a static first-stop argmax), plus the vectorized
+pool lookup for all-occupied-mismatch queries. It is one compiled XLA
+program on every backend: a query touches a few random cells of planes
+far larger than VMEM, which XLA's gathers fetch from HBM directly.
+``repro.sketch.query`` routes through it; ``edge_query_pallas`` is the
+standalone single-sketch drop-in kept for tests and direct use.
 """
 
 from __future__ import annotations
@@ -20,26 +22,53 @@ import jax.numpy as jnp
 from repro.core import hashing as hsh
 from repro.core.lsketch import edge_probes, precompute
 from repro.core.queries import QueryPlanes, build_query_planes
-from repro.core.types import LSketchConfig, LSketchState
+from repro.core.types import EMPTY, LSketchConfig, LSketchState
 
-from .kernel import (sketch_query_kernel, sketch_query_kernel_sharded,
-                     sketch_query_xla)
-
-__all__ = ["edge_query_planes", "edge_query_pallas", "sketch_query_kernel"]
+__all__ = ["edge_query_planes", "edge_query_pallas", "sketch_query_xla"]
 
 
-def _pad_to(x, mult, fill=0):
-    n = x.shape[0]
-    pad = (-n) % mult
-    if pad == 0:
-        return x, n
-    padding = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(x, padding, constant_values=fill), n
+def sketch_query_xla(rows, cols, keys, le_idx, key_plane, cw, pw):
+    """The ordered probe walk on window-reduced planes, vectorized over
+    shards x queries (integer adds/selects only).
+
+    rows/cols/keys: [nq, s]; le_idx: [nq] or None (skip the label plane);
+    key_plane/cw: [S, 2, d, d]; pw: [S, 2, d, d, c].
+    Returns (w [S, nq], w_label [S, nq], go_pool [S, nq]).
+
+    The walk needs no loop at all: per query the s*2 candidates are
+    gathered in paper order (probe-major, twin-minor) and the first
+    (match | empty) is a static argmax — the same formulation as the
+    dense reference, but on window-reduced planes (no ``k`` axis rides
+    the gathers). Traced (not jitted) — compose inside a jitted caller.
+    """
+    S = key_plane.shape[0]
+    nq, s = rows.shape
+    # [S, nq, s, 2] candidates in paper order
+    cur = key_plane[:, :, rows, cols]  # [S, 2, nq, s]
+    cur = jnp.moveaxis(cur, 1, -1)  # [S, nq, s, 2]
+    is_m = (cur == keys[None, :, :, None]).reshape(S, nq, s * 2)
+    is_e = (cur == EMPTY).reshape(S, nq, s * 2)
+    stop = is_m | is_e
+    any_stop = stop.any(-1)
+    first = jnp.argmax(stop, -1)  # [S, nq]
+    hit = jnp.take_along_axis(is_m, first[..., None], -1)[..., 0] & any_stop
+    pi, tz = first // 2, first % 2
+    rr = jnp.take_along_axis(jnp.broadcast_to(rows, (S, nq, s)),
+                             pi[..., None], -1)[..., 0]
+    cc = jnp.take_along_axis(jnp.broadcast_to(cols, (S, nq, s)),
+                             pi[..., None], -1)[..., 0]
+    s_idx = jnp.arange(S, dtype=jnp.int32)[:, None]
+    w = jnp.where(hit, cw[s_idx, tz, rr, cc], 0)
+    if le_idx is None:
+        wl = jnp.zeros_like(w)
+    else:
+        wl = jnp.where(hit, pw[s_idx, tz, rr, cc,
+                               le_idx[None, :].astype(jnp.int32)], 0)
+    return w, wl, ~any_stop
 
 
 def edge_query_planes(cfg: LSketchConfig, planes: QueryPlanes, src, dst,
-                      labels, with_le: bool = True, interpret: bool = True,
-                      _kernel_interpret: bool = False,
+                      labels, with_le: bool = True,
                       axis_name: str | None = None):
     """Batched edge queries on window-reduced planes, all shards at once.
 
@@ -48,10 +77,6 @@ def edge_query_planes(cfg: LSketchConfig, planes: QueryPlanes, src, dst,
     [S, B] — per-shard partials; the caller sums over the shard axis
     (hash partitioning makes shard estimates disjoint).
 
-    ``interpret=True`` (the non-TPU setting) routes the matrix walk to
-    the compiled XLA lowering — bit-identical, never interpreted.
-    ``_kernel_interpret`` (tests only): run the hardware-kernel branch in
-    Pallas interpret mode — the only way to exercise it on CPU.
     Traced (not jitted) — compose inside a jitted caller.
 
     ``axis_name`` makes this a ``shard_map``-compatible entry point
@@ -72,8 +97,7 @@ def edge_query_planes(cfg: LSketchConfig, planes: QueryPlanes, src, dst,
         flat = jax.tree.map(
             lambda x: jnp.reshape(x, (H * S,) + x.shape[2:]), planes)
         w, wl = edge_query_planes(cfg, flat, src, dst, labels,
-                                  with_le=with_le, interpret=interpret,
-                                  _kernel_interpret=_kernel_interpret)
+                                  with_le=with_le)
         w = jnp.sum(w.reshape((H, S) + w.shape[1:]), axis=1)
         wl = jnp.sum(wl.reshape((H, S) + wl.shape[1:]), axis=1)
         if axis_name is not None:
@@ -87,22 +111,8 @@ def edge_query_planes(cfg: LSketchConfig, planes: QueryPlanes, src, dst,
     le_idx = hsh.edge_label_bucket(le, cfg.c, cfg.seed) if with_le else None
     S = planes.cw.shape[0]
 
-    if interpret and not _kernel_interpret:
-        w, wl, go_pool = sketch_query_xla(pr.rows, pr.cols, pr.keys, le_idx,
-                                          planes.key, planes.cw, planes.pw)
-    else:
-        rows, n = _pad_to(pr.rows, 128)
-        cols, _ = _pad_to(pr.cols, 128)
-        keys, _ = _pad_to(pr.keys, 128, fill=-2)  # never matches, never EMPTY
-        lei, _ = _pad_to(le_idx if le_idx is not None
-                         else jnp.zeros_like(pr.rows[:, 0]), 128)
-        w, wl, go_pool = sketch_query_kernel_sharded(
-            rows, cols, keys, lei, planes.key, planes.cw, planes.pw,
-            n_shards=S, d=cfg.d, s=cfg.s, c=cfg.c,
-            interpret=_kernel_interpret)
-        w, wl, go_pool = w[:, :n], wl[:, :n], go_pool[:, :n]
-        if le_idx is None:
-            wl = jnp.zeros_like(w)
+    w, wl, go_pool = sketch_query_xla(pr.rows, pr.cols, pr.keys, le_idx,
+                                      planes.key, planes.cw, planes.pw)
 
     # pool lookup for all-occupied-mismatch queries (vectorized, per shard)
     ps = hsh.pool_slot_seq(pr.pid_src, pr.pid_dst, cfg.pool_capacity,
@@ -124,29 +134,12 @@ def edge_query_planes(cfg: LSketchConfig, planes: QueryPlanes, src, dst,
     return maybe_psum_partials(w, wl, axis_name)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5),
-                   static_argnames=("interpret",))
-def _edge_query_pallas(cfg: LSketchConfig, state: LSketchState, src, dst,
-                       labels, last: int | None = None, *,
-                       interpret: bool = True):
+@functools.partial(jax.jit, static_argnums=(0, 5))
+def edge_query_pallas(cfg: LSketchConfig, state: LSketchState, src, dst,
+                      labels, last: int | None = None):
+    """Planes-path equivalent of ``repro.core.edge_query`` (both outputs)
+    for one plain sketch state."""
     lifted = jax.tree.map(lambda x: x[None], state)
     planes = build_query_planes(cfg, lifted, last)
-    w, wl = edge_query_planes(cfg, planes, src, dst, labels, with_le=True,
-                              interpret=interpret)
+    w, wl = edge_query_planes(cfg, planes, src, dst, labels, with_le=True)
     return w[0], wl[0]
-
-
-def edge_query_pallas(cfg: LSketchConfig, state: LSketchState, src, dst,
-                      labels, last: int | None = None,
-                      interpret: bool | None = None):
-    """Kernel-backed equivalent of ``repro.core.edge_query`` (both outputs).
-
-    ``interpret`` is backend-derived by default (True off TPU, same rule
-    as the insert kernels) and only meaningful on the real Pallas branch:
-    with ``interpret=True`` the walk runs as the compiled XLA lowering —
-    the pallas query path never interprets.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _edge_query_pallas(cfg, state, src, dst, labels, last,
-                              interpret=interpret)

@@ -1,14 +1,15 @@
-"""Wrappers for the vertex/label aggregate query kernels (out/in, pool).
+"""Vertex/label aggregate queries on window-reduced planes (out/in, pool).
 
 ``vertex_query_planes`` and ``label_aggregate_planes`` are the vertex-side
-middles of the "pallas" query path (DESIGN.md §8), operating on pre-reduced
-shard-stacked ``QueryPlanes``:
+middles of the "pallas" (planes) query path (DESIGN.md §8), operating on
+pre-reduced shard-stacked ``QueryPlanes``. Both are compiled XLA programs
+on every backend:
 
-  * vertex aggregates run the r-row masked scan — the shard-axis Pallas
-    kernel on TPU, its compiled XLA lowering elsewhere (never interpreted);
-  * label aggregates are a dense masked reduction over the planes (matmul-
-    shaped already — there is no per-query walk to kernelize, so both
-    backends share the one XLA formulation; the plane cache is the win).
+  * vertex aggregates run the r-row masked scan (``vertex_scan_xla``):
+    r gathered rows per query, far fewer bytes than the planes, which do
+    not fit VMEM at deployment widths;
+  * label aggregates are a dense masked reduction over the planes (the
+    plane cache is the win).
 
 ``vertex_query_pallas`` is the standalone single-sketch drop-in kept for
 tests and direct use.
@@ -26,26 +27,60 @@ from repro.core.lsketch import precompute
 from repro.core.queries import QueryPlanes, build_query_planes
 from repro.core.types import EMPTY, LSketchConfig, LSketchState
 
-from repro.kernels.sketch_query.ops import _pad_to
-
-from .kernel import (vertex_scan_kernel, vertex_scan_kernel_sharded,
-                     vertex_scan_xla)
-
 __all__ = ["vertex_query_planes", "label_aggregate_planes",
-           "vertex_query_pallas", "vertex_scan_kernel"]
+           "vertex_query_pallas", "vertex_scan_xla"]
+
+
+def vertex_scan_xla(lines, f, le_idx, key_plane, cw, pw, *, r: int, F: int,
+                    direction: str = "out"):
+    """The r-row masked scan (paper Alg. 4) on window-reduced planes:
+    one static unroll over the r candidate rows, each iteration gathering
+    one row (or, for ``direction="in"``, one column, decoding the
+    destination key fields (i_B, f_B)) for all shards x queries. Peak
+    transient is [S, 2, B, d(, c)] — the label axis never multiplies the
+    r axis. Integer adds only.
+
+    lines: [nq, r] absolute candidate rows (out) / cols (in); f/le_idx:
+    [nq] (le_idx None skips the label plane); key_plane/cw: [S, 2, d, d];
+    pw: [S, 2, d, d, c]. Returns (w [S, nq], w_label [S, nq]).
+    Traced (not jitted) — compose inside a jitted caller.
+    """
+    S = key_plane.shape[0]
+    nq = lines.shape[0]
+    w = jnp.zeros((S, nq), cw.dtype)
+    wl = jnp.zeros((S, nq), pw.dtype)
+    for i in range(r):  # static unroll: peak transient [S, 2, nq, d(, c)]
+        li = lines[:, i]  # [nq]
+        if direction == "out":
+            kg = key_plane[:, :, li]  # [S, 2, nq, d]
+            cg = cw[:, :, li]
+        else:
+            kg = jnp.moveaxis(key_plane[:, :, :, li], 3, 2)  # -> [S, 2, nq, d]
+            cg = jnp.moveaxis(cw[:, :, :, li], 3, 2)
+        ia, ib, fa, fb = hsh.unpack_key(kg, F)
+        idx, fp = (ia, fa) if direction == "out" else (ib, fb)
+        match = (kg != EMPTY) & (idx == i) & (fp == f[None, :, None])
+        w = w + jnp.sum(jnp.where(match, cg, 0), axis=(1, 3))
+        if le_idx is not None:
+            if direction == "out":
+                pg = pw[:, :, li]  # [S, 2, nq, d, c]
+            else:
+                pg = jnp.moveaxis(pw[:, :, :, li], 3, 2)
+            pl_sel = jnp.take_along_axis(
+                pg, le_idx[None, None, :, None, None].astype(jnp.int32),
+                -1)[..., 0]  # [S, 2, nq, d]
+            wl = wl + jnp.sum(jnp.where(match, pl_sel, 0), axis=(1, 3))
+    return w, wl
 
 
 def vertex_query_planes(cfg: LSketchConfig, planes: QueryPlanes, vertex,
                         labels, direction: str = "out", with_le: bool = True,
-                        interpret: bool = True,
-                        _kernel_interpret: bool = False,
                         axis_name: str | None = None):
     """Batched vertex aggregate queries on window-reduced planes.
 
     vertex: int32 [B]; labels: (lv, le) int32 [B] each (``le`` ignored when
     ``with_le`` is False). Returns (w, w_label), each [S, B] per-shard
-    partials. ``interpret``/``_kernel_interpret`` as in
-    ``edge_query_planes``; ``axis_name`` likewise makes this a
+    partials. ``axis_name`` as in ``edge_query_planes`` makes this a
     ``shard_map``-compatible entry point returning ``[B]`` outputs reduced
     via ``core.merge.psum_partials`` (DESIGN.md §9).
     Traced — compose inside a jitted caller.
@@ -60,9 +95,7 @@ def vertex_query_planes(cfg: LSketchConfig, planes: QueryPlanes, vertex,
         flat = jax.tree.map(
             lambda x: jnp.reshape(x, (H * S,) + x.shape[2:]), planes)
         w, wl = vertex_query_planes(cfg, flat, vertex, labels,
-                                    direction=direction, with_le=with_le,
-                                    interpret=interpret,
-                                    _kernel_interpret=_kernel_interpret)
+                                    direction=direction, with_le=with_le)
         w = jnp.sum(w.reshape((H, S) + w.shape[1:]), axis=1)
         wl = jnp.sum(wl.reshape((H, S) + wl.shape[1:]), axis=1)
         if axis_name is not None:
@@ -76,33 +109,8 @@ def vertex_query_planes(cfg: LSketchConfig, planes: QueryPlanes, vertex,
     lines = pre.start[:, None] + pos  # [B, r] absolute row (or col) index
     S = planes.cw.shape[0]
 
-    if interpret and not _kernel_interpret:
-        w, wl = vertex_scan_xla(lines, pre.f, le_idx, planes.key, planes.cw,
-                                planes.pw, r=cfg.r, F=cfg.F,
-                                direction=direction)
-    else:
-        key_plane, cw, pw = planes.key, planes.cw, planes.pw
-        if direction == "in":  # scan columns: transpose planes and swap the
-            # (ia, fa) <-> (ib, fb) packed-key fields so the kernel's
-            # "row-owner" decode reads the destination fields
-            key_plane = jnp.swapaxes(key_plane, 2, 3)
-            cw = jnp.swapaxes(cw, 2, 3)
-            pw = jnp.swapaxes(pw, 2, 3)
-            occupied = key_plane != EMPTY
-            ia, ib, fa, fb = hsh.unpack_key(key_plane, cfg.F)
-            key_plane = jnp.where(occupied,
-                                  hsh.pack_key(ib, ia, fb, fa, cfg.F),
-                                  key_plane)
-        linesP, n = _pad_to(lines, 128)
-        fP, _ = _pad_to(pre.f, 128, fill=-3)  # never matches a fingerprint
-        leP, _ = _pad_to(le_idx if le_idx is not None
-                         else jnp.zeros_like(pre.f), 128)
-        w, wl = vertex_scan_kernel_sharded(
-            linesP, fP, leP, key_plane, cw, pw, n_shards=S, r=cfg.r,
-            F=cfg.F, c=cfg.c, interpret=_kernel_interpret)
-        w, wl = w[:, :n], wl[:, :n]
-        if le_idx is None:
-            wl = jnp.zeros_like(w)
+    w, wl = vertex_scan_xla(lines, pre.f, le_idx, planes.key, planes.cw,
+                            planes.pw, r=cfg.r, F=cfg.F, direction=direction)
 
     # pool contribution: match the stored endpoint id, per shard
     col = 0 if direction == "out" else 1
@@ -185,30 +193,14 @@ def label_aggregate_planes(cfg: LSketchConfig, planes: QueryPlanes, vlabel,
     return maybe_psum_partials(w, wl, axis_name)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4, 5),
-                   static_argnames=("interpret",))
-def _vertex_query_pallas(cfg: LSketchConfig, state: LSketchState, vertex,
-                         labels, direction: str = "out",
-                         last: int | None = None, *, interpret: bool = True):
+@functools.partial(jax.jit, static_argnums=(0, 4, 5))
+def vertex_query_pallas(cfg: LSketchConfig, state: LSketchState, vertex,
+                        labels, direction: str = "out",
+                        last: int | None = None):
+    """Planes-path equivalent of ``repro.core.vertex_query`` for one
+    plain sketch state."""
     lifted = jax.tree.map(lambda x: x[None], state)
     planes = build_query_planes(cfg, lifted, last)
     w, wl = vertex_query_planes(cfg, planes, vertex, labels,
-                                direction=direction, with_le=True,
-                                interpret=interpret)
+                                direction=direction, with_le=True)
     return w[0], wl[0]
-
-
-def vertex_query_pallas(cfg: LSketchConfig, state: LSketchState, vertex,
-                        labels, direction: str = "out",
-                        last: int | None = None,
-                        interpret: bool | None = None):
-    """Kernel-backed equivalent of ``repro.core.vertex_query``.
-
-    ``interpret`` is backend-derived by default (True off TPU, same rule
-    as the insert kernels): the compiled XLA lowering runs everywhere the
-    real Pallas kernel can't — the pallas query path never interprets.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _vertex_query_pallas(cfg, state, vertex, labels, direction, last,
-                                interpret=interpret)

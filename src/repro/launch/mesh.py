@@ -16,12 +16,19 @@ Batch shards over ("pod", "data"); params FSDP over "data" (+"pod" when
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    # Auto axes: the train/dry-run paths shard by NamedSharding + GSPMD
+    # propagation, not the Explicit-axis typing jax.make_mesh now defaults to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_axes(mesh) -> dict:
@@ -41,4 +48,4 @@ def make_smoke_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // max(1, data)))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -47,6 +47,7 @@ from repro import sketch as skt
 from repro.core import LGSConfig, LSketchConfig
 from repro.core.gss import gss_config
 from repro.data.stream import PHONE, edge_batches, generate
+from repro.launch.compile_cache import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -525,6 +526,7 @@ def main(argv=None):
                          "with --mesh/--collective (pool mode is "
                          "host-resident, DESIGN.md §11)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.tenants and (args.mesh or args.collective):
         raise SystemExit("--tenants is host-resident: drop --mesh/"
                          "--collective")

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 from jax.ad_checkpoint import checkpoint_name
-from jax.experimental.shard_map import shard_map
 import jax.numpy as jnp
 
 from repro.distributed.sharding_ctx import constrain
@@ -104,11 +103,11 @@ def moe(params, x, cfg: ModelConfig, token_ids=None,
         from jax.sharding import PartitionSpec as P
         dp = ctx.logical["dp"]
         dspec = dp if len(dp) > 1 else dp[0]
-        xe, fe, pos_c, keep = shard_map(
+        xe, fe, pos_c, keep = jax.shard_map(
             dispatch_local, mesh=ctx.mesh,
             in_specs=(P(dspec, None), P(dspec, None)),
             out_specs=(P(None, dspec, None), P(dspec), P(dspec), P(dspec)),
-            check_rep=False,
+            check_vma=False,
         )(xf, expert_ids)
     else:
         xe, fe, pos_c, keep = dispatch_local(xf, expert_ids)
@@ -137,12 +136,12 @@ def moe(params, x, cfg: ModelConfig, token_ids=None,
         from jax.sharding import PartitionSpec as P
         dp = ctx.logical["dp"]
         dspec = dp if len(dp) > 1 else dp[0]
-        y = shard_map(
+        y = jax.shard_map(
             combine_local, mesh=ctx.mesh,
             in_specs=(P(None, dspec, None), P(dspec), P(dspec), P(dspec),
                       P(dspec, None)),
             out_specs=P(dspec, None),
-            check_rep=False,
+            check_vma=False,
         )(ye, fe, pos_c, keep, gate_vals)
     else:
         y = combine_local(ye, fe, pos_c, keep, gate_vals)

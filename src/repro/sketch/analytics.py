@@ -9,12 +9,14 @@ same cached ``QueryPlanes`` the query kernels use.
 Path contract (same three names as ``query``):
 
   * ``"scan"``   — dense reference: re-reduce the window planes inside the
-    dispatch (no cache), decode with the compiled XLA twin.
-  * ``"pallas"`` — ``query_planes`` cache + the ``kernels/heavy_hitters``
-    cell-decode kernel on TPU (compiled XLA twin on CPU).
+    dispatch (no cache).
+  * ``"pallas"`` — ``query_planes`` cache.
   * ``"collective"`` — the same body under ``shard_map`` on a
     mesh-resident handle: local decode + flatten, ``all_gather`` of the
     (identity, weight) rows, replicated top-k epilogue.
+
+Every path decodes cell owners on the ``kernels/heavy_hitters`` Pallas
+kernel on TPU and on its compiled XLA twin elsewhere.
 
 All three are bit-identical to each other and to the fixed host
 reference (pinned in tests/test_analytics.py): per-identity totals are
@@ -46,20 +48,18 @@ from .query import (_collective_ctx, _count, _lift, _normalize_horizons,
                     _shmap, _shmap_multi, _with_group_window, query_planes,
                     query_planes_multi, resolve_query_path)
 from .spec import SketchSpec
-from .state import ShardedState
+from .state import ShardedState, mesh_context
 
 
 def _planes_topk(cfg, planes, kind: str, k: int, direction: str, *,
-                 interpret: bool, axis_name=None):
+                 axis_name=None):
     if kind == "vertex":
         return heavy_vertices_planes(cfg, planes, k, direction=direction,
-                                     interpret=interpret,
                                      axis_name=axis_name)
     if kind == "edge":
-        return heavy_edges_planes(cfg, planes, k, interpret=interpret,
-                                  axis_name=axis_name)
+        return heavy_edges_planes(cfg, planes, k, axis_name=axis_name)
     return top_labels_planes(cfg, planes, k, direction=direction,
-                             interpret=interpret, axis_name=axis_name)
+                             axis_name=axis_name)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -69,33 +69,31 @@ def _topk_sharded(spec, shards, *, kind, k, direction, last, stacked=True):
     _count("hh_" + kind, "scan")
     shards = _with_group_window(_lift(shards, stacked))
     planes = _cq.build_query_planes(spec.config, shards, last)
-    return _planes_topk(spec.config, planes, kind, k, direction,
-                        interpret=True)
+    return _planes_topk(spec.config, planes, kind, k, direction)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("kind", "k", "direction", "interpret"))
-def _topk_pallas(spec, planes, *, kind, k, direction, interpret):
+                   static_argnames=("kind", "k", "direction"))
+def _topk_pallas(spec, planes, *, kind, k, direction):
     _count("hh_" + kind, "pallas")
-    return _planes_topk(spec.config, planes, kind, k, direction,
-                        interpret=interpret)
+    return _planes_topk(spec.config, planes, kind, k, direction)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("kind", "k", "direction", "interpret"))
-def _topk_collective(spec, ctx, planes, *, kind, k, direction, interpret):
+                   static_argnames=("kind", "k", "direction"))
+def _topk_collective(spec, ctx, planes, *, kind, k, direction):
     _count("hh_" + kind, "collective")
 
     def body(planes):
         return _planes_topk(spec.config, planes, kind, k, direction,
-                            interpret=interpret, axis_name=ctx.axis)
+                            axis_name=ctx.axis)
 
     return _shmap(body, ctx, 0)(planes)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("kind", "k", "direction", "interpret"))
-def _topk_pallas_multi(spec, planes, *, kind, k, direction, interpret):
+                   static_argnames=("kind", "k", "direction"))
+def _topk_pallas_multi(spec, planes, *, kind, k, direction):
     """Horizon-sweep top-k over a stacked ``MultiPlanes``: the per-horizon
     decodes unroll inside ONE jitted program (the decode kernel is not
     vmapped — unrolling keeps the pallas call shapes identical to the
@@ -103,21 +101,20 @@ def _topk_pallas_multi(spec, planes, *, kind, k, direction, interpret):
     _count("hh_" + kind, "pallas-multi")
     H = planes.cw.shape[0]
     outs = [_planes_topk(spec.config, _cq.slice_horizon(planes, i), kind, k,
-                         direction, interpret=interpret) for i in range(H)]
+                         direction) for i in range(H)]
     return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("kind", "k", "direction", "interpret"))
-def _topk_collective_multi(spec, ctx, planes, *, kind, k, direction,
-                           interpret):
+                   static_argnames=("kind", "k", "direction"))
+def _topk_collective_multi(spec, ctx, planes, *, kind, k, direction):
     _count("hh_" + kind, "collective-multi")
 
     def body(planes):
         H = planes.cw.shape[0]
         outs = [_planes_topk(spec.config, _cq.slice_horizon(planes, i), kind,
-                             k, direction, interpret=interpret,
-                             axis_name=ctx.axis) for i in range(H)]
+                             k, direction, axis_name=ctx.axis)
+                for i in range(H)]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
     return _shmap_multi(body, ctx, 0)(planes)
@@ -142,7 +139,12 @@ def _analytics(spec: SketchSpec, state, kind: str, k: int, direction: str,
     path = resolve_query_path(spec, path)
     stacked = isinstance(state, ShardedState)
     shards = state.shards if stacked else state
-    interpret = jax.default_backend() != "tpu"
+    ctx = mesh_context(state) if stacked else None
+    if path == "pallas" and ctx is not None and ctx.divides(spec.n_shards):
+        # a mesh-placed handle decodes each shard block on its own device
+        # (the collective body, bit-identical): GSPMD cannot partition
+        # the decode kernel over mesh-sharded planes
+        path = "collective"
     if horizons is not None:
         horizons = list(horizons)
         if not horizons:
@@ -159,23 +161,21 @@ def _analytics(spec: SketchSpec, state, kind: str, k: int, direction: str,
         if collective:
             ctx = _collective_ctx(spec, state)
             out = _topk_collective_multi(spec, ctx, planes, kind=kind, k=k,
-                                         direction=direction,
-                                         interpret=interpret)
+                                         direction=direction)
         else:
             out = _topk_pallas_multi(spec, planes, kind=kind, k=k,
-                                     direction=direction,
-                                     interpret=interpret)
+                                     direction=direction)
         sel_arr = jnp.asarray(sel, jnp.int32)
         return jax.tree.map(lambda x: x[sel_arr], out)
     if path == "collective":
         ctx = _collective_ctx(spec, state)
         planes = query_planes(spec, state, last, collective=True)
         return _topk_collective(spec, ctx, planes, kind=kind, k=k,
-                                direction=direction, interpret=interpret)
+                                direction=direction)
     if path == "pallas":
         planes = query_planes(spec, state, last)
         return _topk_pallas(spec, planes, kind=kind, k=k,
-                            direction=direction, interpret=interpret)
+                            direction=direction)
     return _topk_sharded(spec, shards, kind=kind, k=k, direction=direction,
                          last=last, stacked=stacked)
 

@@ -14,9 +14,9 @@
      (``engine.insert.insert_stacked_fused_impl``) over the whole
      ``[n_shards, ...]`` stack: on the Pallas path every single-subwindow
      batch is one shard-axis kernel launch (grid ``(n_shards, n_blocks,
-     n_blocks)``); the vmapped ``lax.scan`` is the multi-subwindow/CPU
-     fallback inside the same dispatch. The insert path follows the
-     engine's selection rule (``engine.insert.resolve_path``): Pallas by
+     n_blocks)``); the vmapped ``lax.scan`` takes multi-subwindow batches
+     inside the same dispatch. The insert path follows the engine's
+     selection rule (``engine.insert.resolve_path``): the binned path by
      default on TPU, compiled scan elsewhere.
 
 ``ingest_single`` is the unstacked 1-shard path the object shims
@@ -150,15 +150,24 @@ def _partition_stack(spec: SketchSpec, batch: EdgeBatch):
     return stacked, jnp.asarray(counts)
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("use_pallas", "interpret", "emit_delta"),
-                   donate_argnums=1)
-def _ingest_stacked_lsketch(cfg, shards, batch: EdgeBatch, n_valid,
-                            use_pallas=False, interpret=False,
-                            emit_delta=False):
-    return eng_insert.insert_stacked_fused_impl(
-        cfg, shards, batch, n_valid, use_pallas=use_pallas,
-        interpret=interpret, emit_delta=emit_delta)
+@functools.partial(jax.jit, static_argnums=(0, 1),
+                   static_argnames=("use_pallas", "emit_delta"),
+                   donate_argnums=2)
+def _ingest_stacked_lsketch(cfg, ctx, shards, batch: EdgeBatch, n_valid,
+                            use_pallas=False, emit_delta=False):
+    """The stacked insert; with a ``MeshContext`` whose axis divides the
+    shard count, each device runs it on its own shard block under
+    ``shard_map`` (shards are independent, and GSPMD cannot partition the
+    Pallas key walk)."""
+    def run(sh, b, nv):
+        return eng_insert.insert_stacked_fused_impl(
+            cfg, sh, b, nv, use_pallas=use_pallas, emit_delta=emit_delta)
+
+    if ctx is None:
+        return run(shards, batch, n_valid)
+    return jax.shard_map(run, mesh=ctx.mesh, in_specs=P(ctx.axis),
+                         out_specs=P(ctx.axis), check_vma=False)(
+                             shards, batch, n_valid)
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=1)
@@ -198,7 +207,8 @@ def _dispatch_stacked(spec: SketchSpec, state: ShardedState, stacked,
     can delta-apply instead of rebuilding. The emission flag is static —
     a handle that was never queried ingests with zero delta overhead."""
     ctx = mesh_context(state)
-    if ctx is not None and ctx.divides(spec.n_shards):
+    local = ctx if ctx is not None and ctx.divides(spec.n_shards) else None
+    if local is not None:
         stacked, n_valid = _place_partition(ctx, stacked, n_valid)
     delta = carry = None
     if spec.kind == "lgs":
@@ -209,13 +219,9 @@ def _dispatch_stacked(spec: SketchSpec, state: ShardedState, stacked,
         if path == "chunked":
             raise ValueError("the stacked ingest has no chunked path")
         carry = _query.planes_delta_base(state)
-        # interpret only matters on the Pallas branch: interpret-mode off
-        # TPU so CPU CI exercises the kernel logic, compiled on TPU
         out = _ingest_stacked_lsketch(
-            spec.config, state.shards, stacked, n_valid,
-            use_pallas=path == "pallas",
-            interpret=jax.default_backend() != "tpu",
-            emit_delta=carry is not None)
+            spec.config, local, state.shards, stacked, n_valid,
+            use_pallas=path == "pallas", emit_delta=carry is not None)
         shards, delta = out if carry is not None else (out, None)
     new = with_mesh(ShardedState(shards=shards), ctx)
     if carry is not None:

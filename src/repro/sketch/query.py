@@ -12,10 +12,11 @@ Three read paths answer the same queries bit-identically (DESIGN.md §8/§9):
   * ``path="scan"`` — the dense reference: ``core/queries.py`` vmapped
     over shards, re-reducing the ``[d, d, 2, k(, c)]`` counter planes
     under the window mask on every dispatch. The conformance baseline.
-  * ``path="pallas"`` — the kernel path: queries run against cached
-    **window-reduced planes** (``core.queries.QueryPlanes``) via the
-    shard-axis ``sketch_query``/``vertex_scan`` kernels on TPU, or their
-    compiled XLA lowerings elsewhere (the pallas path never interprets).
+  * ``path="pallas"`` — the planes path: queries run against cached
+    **window-reduced planes** (``core.queries.QueryPlanes``) through the
+    compiled ``sketch_query``/``vertex_scan`` XLA programs (the same
+    program on every backend; top-k decodes on the ``heavy_hitters``
+    Pallas kernel on TPU).
     The planes are a pure function of ``(state, last)``: they are built
     lazily on the first kernel-path query of a handle and memoized on the
     handle object itself, so a serving loop answering many queries
@@ -61,13 +62,13 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import queries as _q
 from repro.core.lgs import _lgs_edge_query, _lgs_vertex_query
 from repro.core.types import EMPTY
 from repro.engine.window import bucket_size
+from repro.kernels import use_tpu_kernels
 
 from .spec import SketchSpec
 from .state import ShardedState, mesh_context
@@ -150,9 +151,9 @@ class QueryBatch:
 # --------------------------------------------------------------------------
 
 def default_query_path() -> str:
-    """Kernel planes path is the default on TPU; the dense vmapped scan is
+    """The planes path is the default on TPU; the dense vmapped scan is
     the reference/CPU default (same rule as ingest)."""
-    return "pallas" if jax.default_backend() == "tpu" else "scan"
+    return "pallas" if use_tpu_kernels() else "scan"
 
 
 def resolve_query_path(spec: SketchSpec, path: str = "auto") -> str:
@@ -329,11 +330,11 @@ def _build_planes_collective(spec, mesh, axis, shards, *, horizon):
             sh, cur_widx=jnp.broadcast_to(g, sh.cur_widx.shape))
         return _q.build_query_planes(spec.config, sh, horizon)
 
-    # check_rep=False: the bodies use gathers/scatters that predate the
-    # replication-rule registry; correctness is pinned by the scan parity
-    # tests, not the rep checker
-    return shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-                     check_rep=False)(shards)
+    # check_vma=False: the bodies mix shard-varying and replicated values
+    # through gathers/scatters; correctness is pinned by the scan parity
+    # tests, not the varying-manual-axes checker
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis), check_vma=False)(shards)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -368,9 +369,9 @@ def _apply_planes_delta_collective(spec, mesh, axis, shards, planes, delta,
 
     dspec = _q.PlanesDelta(ok=P(axis), slot=P(axis), d_c=P(axis), d_p=P(axis),
                            d_pool_c=P(axis), d_pool_p=P(axis))
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), dspec),
-                     out_specs=P(axis), check_rep=False)(shards, planes,
-                                                         delta)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), dspec),
+                         out_specs=P(axis), check_vma=False)(shards, planes,
+                                                             delta)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -407,9 +408,9 @@ def _build_planes_collective_multi(spec, mesh, axis, shards, *, horizons):
             sh, cur_widx=jnp.broadcast_to(g, sh.cur_widx.shape))
         return _q.build_query_planes_multi(spec.config, sh, horizons)
 
-    return shard_map(body, mesh=mesh, in_specs=P(axis),
-                     out_specs=_multi_pspecs(axis),
-                     check_rep=False)(shards)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=_multi_pspecs(axis),
+                         check_vma=False)(shards)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -440,8 +441,9 @@ def _apply_planes_delta_collective_multi(spec, mesh, axis, shards, planes,
     dspec = _q.PlanesDelta(ok=P(axis), slot=P(axis), d_c=P(axis), d_p=P(axis),
                            d_pool_c=P(axis), d_pool_p=P(axis))
     mspec = _multi_pspecs(axis)
-    return shard_map(body, mesh=mesh, in_specs=(P(axis), mspec, dspec),
-                     out_specs=mspec, check_rep=False)(shards, planes, delta)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), mspec, dspec),
+                         out_specs=mspec, check_vma=False)(shards, planes,
+                                                           delta)
 
 
 def planes_delta_base(state):
@@ -754,24 +756,22 @@ def _label_sharded(spec, shards, lv, les, *, with_le, direction, last,
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("with_le", "interpret"))
-def _edge_pallas(spec, planes, src, dst, la, lb, les, *, with_le, interpret):
+                   static_argnames=("with_le",))
+def _edge_pallas(spec, planes, src, dst, la, lb, les, *, with_le):
     _count("edge", "pallas")
     from repro.kernels.sketch_query.ops import edge_query_planes
     w, wl = edge_query_planes(spec.config, planes, src, dst, (la, lb, les),
-                              with_le=with_le, interpret=interpret)
+                              with_le=with_le)
     return jnp.sum(wl if with_le else w, axis=0)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("with_le", "direction", "interpret"))
-def _vertex_pallas(spec, planes, v, lv, les, *, with_le, direction,
-                   interpret):
+                   static_argnames=("with_le", "direction"))
+def _vertex_pallas(spec, planes, v, lv, les, *, with_le, direction):
     _count("vertex", "pallas")
     from repro.kernels.vertex_scan.ops import vertex_query_planes
     w, wl = vertex_query_planes(spec.config, planes, v, (lv, les),
-                                direction=direction, with_le=with_le,
-                                interpret=interpret)
+                                direction=direction, with_le=with_le)
     return jnp.sum(wl if with_le else w, axis=0)
 
 
@@ -793,25 +793,23 @@ def _label_pallas(spec, planes, lv, les, *, with_le, direction):
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("with_le", "interpret"))
-def _edge_pallas_multi(spec, planes, src, dst, la, lb, les, *, with_le,
-                       interpret):
+                   static_argnames=("with_le",))
+def _edge_pallas_multi(spec, planes, src, dst, la, lb, les, *, with_le):
     _count("edge", "pallas-multi")
     from repro.kernels.sketch_query.ops import edge_query_planes
     w, wl = edge_query_planes(spec.config, planes, src, dst, (la, lb, les),
-                              with_le=with_le, interpret=interpret)
+                              with_le=with_le)
     return wl if with_le else w
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("with_le", "direction", "interpret"))
-def _vertex_pallas_multi(spec, planes, v, lv, les, *, with_le, direction,
-                         interpret):
+                   static_argnames=("with_le", "direction"))
+def _vertex_pallas_multi(spec, planes, v, lv, les, *, with_le,
+                         direction):
     _count("vertex", "pallas-multi")
     from repro.kernels.vertex_scan.ops import vertex_query_planes
     w, wl = vertex_query_planes(spec.config, planes, v, (lv, les),
-                                direction=direction, with_le=with_le,
-                                interpret=interpret)
+                                direction=direction, with_le=with_le)
     return wl if with_le else w
 
 
@@ -834,39 +832,38 @@ def _shmap(body, ctx, n_query_args):
     """shard_map wrapper shared by the collective dispatches: planes are
     sharded on the leading shard axis, query arrays replicated, output
     replicated (already psum-reduced inside the plane ops)."""
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(ctx.axis),) + (P(),) * n_query_args,
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("with_le", "interpret"))
-def _edge_collective(spec, ctx, planes, src, dst, la, lb, les, *, with_le,
-                     interpret):
+                   static_argnames=("with_le",))
+def _edge_collective(spec, ctx, planes, src, dst, la, lb, les, *, with_le):
     _count("edge", "collective")
     from repro.kernels.sketch_query.ops import edge_query_planes
 
     def body(planes, src, dst, la, lb, les):
         w, wl = edge_query_planes(spec.config, planes, src, dst,
                                   (la, lb, les), with_le=with_le,
-                                  interpret=interpret, axis_name=ctx.axis)
+                                  axis_name=ctx.axis)
         return wl if with_le else w
 
     return _shmap(body, ctx, 5)(planes, src, dst, la, lb, les)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("with_le", "direction", "interpret"))
-def _vertex_collective(spec, ctx, planes, v, lv, les, *, with_le, direction,
-                       interpret):
+                   static_argnames=("with_le", "direction"))
+def _vertex_collective(spec, ctx, planes, v, lv, les, *, with_le,
+                       direction):
     _count("vertex", "collective")
     from repro.kernels.vertex_scan.ops import vertex_query_planes
 
     def body(planes, v, lv, les):
         w, wl = vertex_query_planes(spec.config, planes, v, (lv, les),
                                     direction=direction, with_le=with_le,
-                                    interpret=interpret, axis_name=ctx.axis)
+                                    axis_name=ctx.axis)
         return wl if with_le else w
 
     return _shmap(body, ctx, 3)(planes, v, lv, les)
@@ -892,39 +889,39 @@ def _shmap_multi(body, ctx, n_query_args):
     the MultiPlanes shard on their axis-1 shard axis (horizon axis
     replicated), query arrays replicated, output replicated — the multi
     plane ops psum their [H, B] answers internally."""
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(_multi_pspecs(ctx.axis),) + (P(),) * n_query_args,
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("with_le", "interpret"))
+                   static_argnames=("with_le",))
 def _edge_collective_multi(spec, ctx, planes, src, dst, la, lb, les, *,
-                           with_le, interpret):
+                           with_le):
     _count("edge", "collective-multi")
     from repro.kernels.sketch_query.ops import edge_query_planes
 
     def body(planes, src, dst, la, lb, les):
         w, wl = edge_query_planes(spec.config, planes, src, dst,
                                   (la, lb, les), with_le=with_le,
-                                  interpret=interpret, axis_name=ctx.axis)
+                                  axis_name=ctx.axis)
         return wl if with_le else w
 
     return _shmap_multi(body, ctx, 5)(planes, src, dst, la, lb, les)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("with_le", "direction", "interpret"))
+                   static_argnames=("with_le", "direction"))
 def _vertex_collective_multi(spec, ctx, planes, v, lv, les, *, with_le,
-                             direction, interpret):
+                             direction):
     _count("vertex", "collective-multi")
     from repro.kernels.vertex_scan.ops import vertex_query_planes
 
     def body(planes, v, lv, les):
         w, wl = vertex_query_planes(spec.config, planes, v, (lv, les),
                                     direction=direction, with_le=with_le,
-                                    interpret=interpret, axis_name=ctx.axis)
+                                    axis_name=ctx.axis)
         return wl if with_le else w
 
     return _shmap_multi(body, ctx, 3)(planes, v, lv, les)
@@ -976,7 +973,6 @@ def query(spec: SketchSpec, state, q: QueryBatch,
     path = resolve_query_path(spec, path)
     stacked = isinstance(state, ShardedState)
     shards = state.shards if stacked else state
-    interpret = jax.default_backend() != "tpu"
     arrays, with_le, last, n = normalize_query(spec, q)
 
     if q.kind == "edge":
@@ -985,11 +981,11 @@ def query(spec: SketchSpec, state, q: QueryBatch,
             ctx = _collective_ctx(spec, state)
             planes = query_planes(spec, state, last, collective=True)
             out = _edge_collective(spec, ctx, planes, src, dst, la, lb, les,
-                                   with_le=with_le, interpret=interpret)
+                                   with_le=with_le)
         elif path == "pallas":
             planes = query_planes(spec, state, last)
             out = _edge_pallas(spec, planes, src, dst, la, lb, les,
-                               with_le=with_le, interpret=interpret)
+                               with_le=with_le)
         else:
             out = _edge_sharded(spec, shards, src, dst, la, lb, les,
                                 with_le=with_le, last=last, stacked=stacked)
@@ -1001,12 +997,11 @@ def query(spec: SketchSpec, state, q: QueryBatch,
             ctx = _collective_ctx(spec, state)
             planes = query_planes(spec, state, last, collective=True)
             out = _vertex_collective(spec, ctx, planes, v, lv, les,
-                                     with_le=with_le, direction=q.direction,
-                                     interpret=interpret)
+                                     with_le=with_le, direction=q.direction)
         elif path == "pallas":
             planes = query_planes(spec, state, last)
             out = _vertex_pallas(spec, planes, v, lv, les, with_le=with_le,
-                                 direction=q.direction, interpret=interpret)
+                                 direction=q.direction)
         else:
             out = _vertex_sharded(spec, shards, v, lv, les, with_le=with_le,
                                   direction=q.direction, last=last,
@@ -1060,7 +1055,6 @@ def _query_multi(spec: SketchSpec, state, q: QueryBatch,
     uniq, sel = _normalize_horizons(spec, lasts)
     collective = path == "collective"
     planes, _ = query_planes_multi(spec, state, lasts, collective=collective)
-    interpret = jax.default_backend() != "tpu"
     arrays, with_le, _, n = normalize_query(
         spec, dataclasses.replace(q, last=None))
 
@@ -1069,24 +1063,21 @@ def _query_multi(spec: SketchSpec, state, q: QueryBatch,
         if collective:
             ctx = _collective_ctx(spec, state)
             out = _edge_collective_multi(spec, ctx, planes, src, dst, la, lb,
-                                         les, with_le=with_le,
-                                         interpret=interpret)
+                                         les, with_le=with_le)
         else:
             out = _edge_pallas_multi(spec, planes, src, dst, la, lb, les,
-                                     with_le=with_le, interpret=interpret)
+                                     with_le=with_le)
     elif q.kind == "vertex":
         v, lv, les = arrays
         if collective:
             ctx = _collective_ctx(spec, state)
             out = _vertex_collective_multi(spec, ctx, planes, v, lv, les,
                                            with_le=with_le,
-                                           direction=q.direction,
-                                           interpret=interpret)
+                                           direction=q.direction)
         else:
             out = _vertex_pallas_multi(spec, planes, v, lv, les,
                                        with_le=with_le,
-                                       direction=q.direction,
-                                       interpret=interpret)
+                                       direction=q.direction)
     elif q.kind == "label":
         lv, les = arrays
         if collective:

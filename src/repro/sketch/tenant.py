@@ -192,15 +192,15 @@ def _label_pooled(spec, shards, lv, les, *, with_le, direction, last,
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("with_le", "interpret", "groups"))
+                   static_argnames=("with_le", "groups"))
 def _edge_pooled_planes(spec, planes, src, dst, la, lb, les, *, with_le,
-                        interpret, groups):
+                        groups):
     _count("edge", "pooled-pallas")
     from repro.kernels.sketch_query.ops import edge_query_planes
 
     def per_group(gpl, s_, d_, a_, b_, e_):
         w, wl = edge_query_planes(spec.config, gpl, s_, d_, (a_, b_, e_),
-                                  with_le=with_le, interpret=interpret)
+                                  with_le=with_le)
         return jnp.sum(wl if with_le else w, axis=0)
 
     return jax.vmap(per_group)(_grouped(planes, groups), src, dst, la, lb,
@@ -208,17 +208,15 @@ def _edge_pooled_planes(spec, planes, src, dst, la, lb, les, *, with_le,
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("with_le", "direction", "interpret",
-                                    "groups"))
+                   static_argnames=("with_le", "direction", "groups"))
 def _vertex_pooled_planes(spec, planes, v, lv, les, *, with_le, direction,
-                          interpret, groups):
+                          groups):
     _count("vertex", "pooled-pallas")
     from repro.kernels.vertex_scan.ops import vertex_query_planes
 
     def per_group(gpl, v_, l_, e_):
         w, wl = vertex_query_planes(spec.config, gpl, v_, (l_, e_),
-                                    direction=direction, with_le=with_le,
-                                    interpret=interpret)
+                                    direction=direction, with_le=with_le)
         return jnp.sum(wl if with_le else w, axis=0)
 
     return jax.vmap(per_group)(_grouped(planes, groups), v, lv, les)
@@ -240,10 +238,8 @@ def _label_pooled_planes(spec, planes, lv, les, *, with_le, direction,
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("kind", "k", "direction", "interpret",
-                                    "groups"))
-def _topk_pooled_planes(spec, planes, *, kind, k, direction, interpret,
-                        groups):
+                   static_argnames=("kind", "k", "direction", "groups"))
+def _topk_pooled_planes(spec, planes, *, kind, k, direction, groups):
     """Per-tenant heavy-hitter top-k over grouped pooled planes — the
     analytics portfolio (DESIGN.md §12) vmapped across tenant blocks, one
     dispatch for the whole pool. Each group's epilogue sees only its own
@@ -256,22 +252,17 @@ def _topk_pooled_planes(spec, planes, *, kind, k, direction, interpret,
     def per_group(gpl):
         if kind == "vertex":
             return heavy_vertices_planes(spec.config, gpl, k,
-                                         direction=direction,
-                                         interpret=interpret)
+                                         direction=direction)
         if kind == "edge":
-            return heavy_edges_planes(spec.config, gpl, k,
-                                      interpret=interpret)
-        return top_labels_planes(spec.config, gpl, k, direction=direction,
-                                 interpret=interpret)
+            return heavy_edges_planes(spec.config, gpl, k)
+        return top_labels_planes(spec.config, gpl, k, direction=direction)
 
     return jax.vmap(per_group)(_grouped(planes, groups))
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("kind", "k", "direction", "interpret",
-                                    "groups"))
-def _topk_pooled_planes_multi(spec, planes, *, kind, k, direction, interpret,
-                              groups):
+                   static_argnames=("kind", "k", "direction", "groups"))
+def _topk_pooled_planes_multi(spec, planes, *, kind, k, direction, groups):
     """Horizon-sweep twin of ``_topk_pooled_planes``: slice each horizon
     off the stacked pooled ``MultiPlanes`` (DESIGN.md §14), run the same
     grouped decode, and stack — the per-horizon decodes unroll inside ONE
@@ -283,13 +274,10 @@ def _topk_pooled_planes_multi(spec, planes, *, kind, k, direction, interpret,
     def per_group(gpl):
         if kind == "vertex":
             return heavy_vertices_planes(spec.config, gpl, k,
-                                         direction=direction,
-                                         interpret=interpret)
+                                         direction=direction)
         if kind == "edge":
-            return heavy_edges_planes(spec.config, gpl, k,
-                                      interpret=interpret)
-        return top_labels_planes(spec.config, gpl, k, direction=direction,
-                                 interpret=interpret)
+            return heavy_edges_planes(spec.config, gpl, k)
+        return top_labels_planes(spec.config, gpl, k, direction=direction)
 
     H = planes.cw.shape[0]
     outs = [jax.vmap(per_group)(_grouped(_q.slice_horizon(planes, i), groups))
@@ -743,17 +731,16 @@ class TenantPool:
         groups = self.n_slots
         garrays, with_le, last, kind, direction, spans = _group_queries(
             self.spec, slotted, groups)
-        interpret = jax.default_backend() != "tpu"
         if path == "pallas":
             planes = query_planes(self.spec, state, last, groups=groups)
             if kind == "edge":
                 out = _edge_pooled_planes(
                     self.spec, planes, *garrays, with_le=with_le,
-                    interpret=interpret, groups=groups)
+                    groups=groups)
             elif kind == "vertex":
                 out = _vertex_pooled_planes(
                     self.spec, planes, *garrays, with_le=with_le,
-                    direction=direction, interpret=interpret, groups=groups)
+                    direction=direction, groups=groups)
             else:
                 out = _label_pooled_planes(
                     self.spec, planes, *garrays, with_le=with_le,
@@ -810,7 +797,6 @@ class TenantPool:
         tenant_ids = list(tenant_ids)
         if not tenant_ids:
             return []
-        interpret = jax.default_backend() != "tpu"
         if horizons is not None:
             horizons = list(horizons)
             if not horizons:
@@ -829,7 +815,7 @@ class TenantPool:
                                            groups=self.n_slots)
             out = _topk_pooled_planes_multi(
                 self.spec, planes, kind=kind, k=k, direction=direction,
-                interpret=interpret, groups=self.n_slots)
+                groups=self.n_slots)
             sel_arr = jnp.asarray(sel, jnp.int32)
             out = jax.tree.map(lambda x: x[sel_arr], out)
             return [jax.tree.map(lambda x: x[:, s], out) for s in slots]
@@ -839,5 +825,5 @@ class TenantPool:
         planes = query_planes(self.spec, state, last, groups=self.n_slots)
         out = _topk_pooled_planes(
             self.spec, planes, kind=kind, k=k, direction=direction,
-            interpret=interpret, groups=self.n_slots)
+            groups=self.n_slots)
         return [jax.tree.map(lambda x: x[s], out) for s in slots]

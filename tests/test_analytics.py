@@ -1,6 +1,7 @@
 """Heavy-hitter / triangle analytics on top of the sketch (paper §1 apps),
 plus the handle-layer portfolio (DESIGN.md §12): bit-parity of the
-scan/pallas/kernel-interpret paths against the fixed host reference twin,
+scan/pallas paths against the fixed host reference twin, the decode
+kernel (interpret mode) against its XLA twin,
 pool-overflow ranking, per-tenant pooled top-k, and batched reachability."""
 
 import dataclasses
@@ -13,9 +14,9 @@ from repro.core import EdgeBatch, LSketch, LSketchConfig
 from repro.core.analytics import (heavy_hitter_edges, heavy_hitter_vertices,
                                   top_label_blocks)
 from repro.core.lsketch import precompute
-from repro.kernels.heavy_hitters.ops import (heavy_edges_planes,
-                                             heavy_vertices_planes,
-                                             top_labels_planes)
+from repro.kernels.heavy_hitters.kernel import (cell_decode_kernel_sharded,
+                                                cell_decode_xla)
+from repro.kernels.heavy_hitters.ops import _static_blocks
 
 CFG = LSketchConfig(d=64, n_blocks=2, F=512, r=4, s=8, c=4, k=4,
                     window_size=400, pool_capacity=1024, pool_probes=16)
@@ -104,8 +105,8 @@ def _merged_host_ref(fn, spec, st, k, **kw):
 
 
 def test_handle_topk_matches_host_reference_all_paths():
-    """scan and pallas (XLA-twin + interpreted-kernel) paths are
-    bit-identical to the fixed host reference, 1 and 4 shards."""
+    """scan and pallas paths are bit-identical to the fixed host
+    reference, 1 and 4 shards."""
     rng = np.random.default_rng(2)
     arrays = _planted_stream(rng)
     for ns in (1, 4):
@@ -132,20 +133,22 @@ def test_handle_topk_matches_host_reference_all_paths():
 
 
 def test_kernel_interpret_matches_xla_twin():
-    """The actual Pallas kernel body (interpreter mode) is bit-identical
-    to the compiled XLA decode twin for every kind."""
+    """The Pallas cell-decode kernel (interpreter mode, two row bands at
+    d=128) is bit-identical to the compiled XLA decode twin that every
+    top-k kind decodes with off TPU."""
     rng = np.random.default_rng(3)
-    spec, st = _handle(4, _planted_stream(rng))
+    cfg = CFG.replace(d=128)
+    spec, st = _handle(4, _planted_stream(rng), cfg=cfg)
     planes = skt.query_planes(spec, st)
-    for fn, kw in ((heavy_vertices_planes, {"direction": "out"}),
-                   (heavy_vertices_planes, {"direction": "in"}),
-                   (heavy_edges_planes, {}),
-                   (top_labels_planes, {"direction": "out"})):
-        a = fn(spec.config, planes, 6, interpret=True, **kw)
-        b = fn(spec.config, planes, 6, interpret=True,
-               _kernel_interpret=True, **kw)
-        for x, y in zip(a, b):
-            assert np.array_equal(np.asarray(x), np.asarray(y)), (fn, kw)
+    starts, widths = _static_blocks(cfg)
+    ker = cell_decode_kernel_sharded(planes.key, starts=starts,
+                                     widths=widths, r=cfg.r, F=cfg.F,
+                                     interpret=True)
+    xla = cell_decode_xla(planes.key, starts=jnp.asarray(starts),
+                          widths=jnp.asarray(widths), r=cfg.r, F=cfg.F)
+    assert int(jnp.sum(planes.key != -1)) > 0
+    for x, y in zip(ker, xla):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_topk_respects_last_horizon():

@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs their references."""
 
 import numpy as np
 import pytest
@@ -53,63 +53,6 @@ def test_sketch_insert_sequential_batches_compose():
     ker = insert_window_batch_pallas(cfg, ker, b2, 2)
     for la, lb in zip(jax.tree.leaves(ref), jax.tree.leaves(ker)):
         assert jnp.array_equal(la, lb)
-
-
-@pytest.mark.parametrize("d,nb,F,r,s,c,k,n_shards", [
-    (32, 2, 256, 2, 2, 2, 1, 1),
-    (64, 2, 512, 4, 4, 4, 4, 2),
-])
-def test_sketch_query_sharded_kernel_matches_xla_twin(d, nb, F, r, s, c, k,
-                                                      n_shards):
-    """The shard-axis query kernels (Pallas interpret mode) are
-    bit-identical to their compiled XLA lowerings on the same planes —
-    the anchor that ties the TPU program to the production CPU route,
-    mirroring the sketch_insert kernel/twin anchor."""
-    from repro import sketch as skt
-    from repro.core.queries import build_query_planes
-    from repro.kernels.sketch_query.ops import edge_query_planes
-    from repro.kernels.vertex_scan.ops import vertex_query_planes
-    from repro.sketch.query import _with_global_window
-
-    cfg = LSketchConfig(d=d, n_blocks=nb, F=F, r=r, s=s, c=c, k=k,
-                        window_size=0 if k == 1 else 100,
-                        pool_capacity=256, pool_probes=8)
-    rng = np.random.default_rng(d + n_shards)
-    spec = skt.SketchSpec(kind="lsketch", config=cfg, n_shards=n_shards)
-    state = skt.create(spec)
-    for t in (10, 60, 120):
-        state = skt.ingest(spec, state, _mk_batch(rng, 150, t=t))
-    planes = jax.jit(
-        lambda sh: build_query_planes(cfg, sh, None))(
-            _with_global_window(state.shards))
-
-    nq = 100
-    qs = jnp.asarray(rng.integers(0, 60, nq), jnp.int32)
-    qd = jnp.asarray(rng.integers(0, 60, nq), jnp.int32)
-    labels = (qs % 3, qd % 3, jnp.asarray(rng.integers(0, 6, nq), jnp.int32))
-    for with_le in (False, True):
-        xla = jax.jit(lambda p, wl=with_le: edge_query_planes(
-            cfg, p, qs, qd, labels, with_le=wl, interpret=True))(planes)
-        ker = jax.jit(lambda p, wl=with_le: edge_query_planes(
-            cfg, p, qs, qd, labels, with_le=wl, interpret=False,
-            _kernel_interpret=True))(planes)
-        for a, b in zip(xla, ker):
-            assert jnp.array_equal(a, b)
-
-    vq = jnp.arange(30, dtype=jnp.int32)
-    vl = (vq % 3, jnp.asarray(rng.integers(0, 6, 30), jnp.int32))
-    for direction in ("out", "in"):
-        for with_le in (False, True):
-            xla = jax.jit(lambda p, dr=direction, wl=with_le:
-                          vertex_query_planes(cfg, p, vq, vl, direction=dr,
-                                              with_le=wl, interpret=True))(
-                              planes)
-            ker = jax.jit(lambda p, dr=direction, wl=with_le:
-                          vertex_query_planes(cfg, p, vq, vl, direction=dr,
-                                              with_le=wl, interpret=False,
-                                              _kernel_interpret=True))(planes)
-            for a, b in zip(xla, ker):
-                assert jnp.array_equal(a, b), (direction, with_le)
 
 
 @pytest.mark.parametrize("B,Hq,Hkv,L,dh,dtype", [

@@ -46,7 +46,7 @@ def test_dryrun_machinery_small_mesh():
         def tiny_mesh(*, multi_pod=False):
             shape = (2, 2, 2) if multi_pod else (2, 2)
             axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-            return jax.make_mesh(shape, axes)
+            return mesh_mod.make_mesh(shape, axes)
 
         mesh_mod.make_production_mesh = tiny_mesh
         dr.make_production_mesh = tiny_mesh

@@ -454,77 +454,67 @@ def test_sharded_pallas_scan_parity_property():
     check()
 
 
-def test_pallas_kernel_bit_identical_to_xla_twin():
-    """The Pallas grid kernel (interpret mode) and its pure-XLA model
-    (``sketch_insert_tiles_xla``) agree tensor-for-tensor on identical
-    binned inputs — the anchor that ties the TPU program to the compiled
-    path the CPU runs."""
+def _walk_inputs(seed, S, B, n_vertices, w_lo):
+    """Addressed probes, label buckets and weights for a [S, B] stack."""
     from repro.core import hashing as hsh
-    from repro.core.lsketch import edge_probes, precompute
-    from repro.kernels.sketch_insert.kernel import (
-        sketch_insert_kernel_sharded, sketch_insert_tiles_xla)
-    from repro.kernels.sketch_insert.ops import _bin_batch
+    from repro.core.lsketch import edge_probes
 
-    cfg = CFG
-    rng = np.random.default_rng(23)
-    S, B = 2, 128
-    src = jnp.asarray(rng.integers(0, 300, (S, B)), jnp.int32)
-    dst = jnp.asarray(rng.integers(0, 300, (S, B)), jnp.int32)
+    rng = np.random.default_rng(seed)
+    src = jnp.asarray(rng.integers(0, n_vertices, (S, B)), jnp.int32)
+    dst = jnp.asarray(rng.integers(0, n_vertices, (S, B)), jnp.int32)
     le = jnp.asarray(rng.integers(0, 5, (S, B)), jnp.int32)
-    w = jnp.asarray(rng.integers(0, 3, (S, B)), jnp.int32)  # incl. zeros
-    probes = edge_probes(cfg, precompute(cfg, src, src % 3),
-                         precompute(cfg, dst, dst % 3))
-    lei = hsh.edge_label_bucket(le, cfg.c, cfg.seed)
-    bins, _, counts = jax.vmap(
-        lambda p, l, ww: _bin_batch(cfg, p, l, ww, B))(probes, lei, w)
-    key = jnp.full((S, 2, cfg.d, cfg.d), EMPTY, jnp.int32)
-    C = jnp.zeros((S, 2, cfg.d, cfg.d), jnp.int32)
-    P = jnp.zeros((S, 2, cfg.d, cfg.d, cfg.c), jnp.int32)
-    kw = dict(n_shards=S, n_blocks=cfg.n_blocks, b=cfg.b, s=cfg.s,
-              c=cfg.c, max_bin=B)
-    kernel_out = sketch_insert_kernel_sharded(*bins, key, C, P, **kw,
-                                              interpret=True)
-    twin_out = sketch_insert_tiles_xla(*bins, key, C, P,
-                                       jnp.max(counts), **kw)
-    for a, b in zip(kernel_out, twin_out):
+    w = jnp.asarray(rng.integers(w_lo, 3, (S, B)), jnp.int32)
+    probes = edge_probes(CFG, precompute(CFG, src, src % 3),
+                         precompute(CFG, dst, dst % 3))
+    return probes, hsh.edge_label_bucket(le, CFG.c, CFG.seed), w
+
+
+def test_pallas_kernel_bit_identical_to_xla_twin():
+    """The Pallas key-walk kernel (interpret mode) and its XLA lowering
+    (``sketch_insert_stream_walk``) return the same keys and landing
+    cells on identical inputs — the anchor that ties the TPU program to
+    the compiled path the CPU runs. Bins past one SMEM chunk, zero
+    weights and a half-full starting key plane are all covered."""
+    from repro.kernels.sketch_insert.kernel import CHUNK
+    from repro.kernels.sketch_insert.ops import (_key_walk_pallas,
+                                                 _key_walk_xla)
+
+    S, B = 2, 640  # ~160 edges per bin: every bin spans two chunks
+    probes, _, w = _walk_inputs(23, S, B, 300, 0)  # weights incl. zeros
+    warm, _, w0 = _walk_inputs(5, S, 256, 300, 1)
+    key0 = jnp.full((S, 2, CFG.d, CFG.d), EMPTY, jnp.int32)
+    key_t, _, _ = _key_walk_xla(CFG, warm, w0, key0, 256)
+    assert B // CFG.n_blocks ** 2 > CHUNK
+    ker = _key_walk_pallas(CFG, probes, w, key_t, B, interpret=True)
+    xla = _key_walk_xla(CFG, probes, w, key_t, B)
+    for a, b in zip(ker, xla):
         assert bool(jnp.array_equal(a, b))
+    assert int(jnp.sum(ker[1] > 0)) > 0
 
 
 def test_small_max_bin_drops_overflow_to_pool_on_both_lowerings():
     """``max_bin`` is a tuning knob: a bin's overflow edges are marked
-    not-inserted and fall to the additional pool. The CPU stream-walk
-    lowering must reproduce the kernel's truncated-bin semantics —
-    regression for an interpret-path divergence where the walk ignored
-    ``max_bin`` and inserted overflow into the matrix instead. Both sides
-    run the *production* ``matrix_insert_binned_sharded`` branches (the
-    kernel branch via its interpret-mode test hook)."""
-    import functools
-    from repro.core import hashing as hsh
-    from repro.core.lsketch import edge_probes, precompute
-    from repro.kernels.sketch_insert.ops import matrix_insert_binned_sharded
+    not-inserted and fall to the additional pool. The XLA stream walk
+    must reproduce the kernel's truncated-bin semantics — regression for
+    a divergence where the walk ignored ``max_bin`` and inserted
+    overflow into the matrix instead."""
+    from repro.kernels.sketch_insert.ops import (
+        _key_walk_pallas, _key_walk_xla, matrix_insert_binned_sharded)
 
-    cfg = CFG
-    rng = np.random.default_rng(24)
     S, B, MB = 2, 96, 4  # MB far below the per-bin fill
-    src = jnp.asarray(rng.integers(0, 50, (S, B)), jnp.int32)
-    dst = jnp.asarray(rng.integers(0, 50, (S, B)), jnp.int32)
-    le = jnp.asarray(rng.integers(0, 5, (S, B)), jnp.int32)
-    w = jnp.asarray(rng.integers(1, 3, (S, B)), jnp.int32)
-    probes = edge_probes(cfg, precompute(cfg, src, src % 3),
-                         precompute(cfg, dst, dst % 3))
-    lei = hsh.edge_label_bucket(le, cfg.c, cfg.seed)
-    base = jax.tree.map(lambda x: jnp.stack([x] * S), init_state(cfg))
-    slot = jnp.zeros((S,), jnp.int32)
-
-    run = functools.partial(matrix_insert_binned_sharded, cfg)
-    got = jax.jit(lambda st: run(st, probes, lei, w, slot, max_bin=MB,
-                                 interpret=True))(base)
-    ref = jax.jit(lambda st: run(st, probes, lei, w, slot, max_bin=MB,
-                                 interpret=False, _kernel_interpret=True)
-                  )(base)
-    assert _states_equal(got, ref)
+    probes, lei, w = _walk_inputs(24, S, B, 50, 1)
+    key0 = jnp.full((S, 2, CFG.d, CFG.d), EMPTY, jnp.int32)
+    ker = _key_walk_pallas(CFG, probes, w, key0, MB, interpret=True)
+    xla = _key_walk_xla(CFG, probes, w, key0, MB)
+    for a, b in zip(ker, xla):
+        assert bool(jnp.array_equal(a, b))
+    assert int(jnp.sum(xla[1] > 0)) <= S * CFG.n_blocks ** 2 * MB
+    base = jax.tree.map(lambda x: jnp.stack([x] * S), init_state(CFG))
+    out = jax.jit(lambda st: matrix_insert_binned_sharded(
+        CFG, st, probes, lei, w, jnp.zeros((S,), jnp.int32),
+        max_bin=MB))(base)
     # the cap must actually bite: some edges landed in the pool
-    assert int(jnp.sum(ref.pool_key[..., 0] != EMPTY)) > 0
+    assert int(jnp.sum(out.pool_key[..., 0] != EMPTY)) > 0
 
 
 # --------------------------------------------------------------------------
