@@ -1,0 +1,15 @@
+"""Stacked ingest dispatch: device time of ``_ingest_stacked_lsketch``
+(key walk or boundary scan, ring reclaim, delta slices) per launch, from
+the device trace. Each ingest call launches the program once (the call
+dispatches the batch staged by the one before it); where the traced
+window holds launches that differ from its ingest calls by more than one
+at each end (events dropped inside it), the metric is left out rather
+than averaged over the launches that survived."""
+
+
+def read(ctx):
+    m = ctx.trace["modules"].get("_ingest_stacked_lsketch")
+    calls = ctx.trace["host_n"].get("ingest", 0)
+    if not m or not m[1] or abs(m[1] - calls) > 2:
+        return None
+    return m[0] / m[1] * 1e3
