@@ -186,6 +186,23 @@ def sharded_ingest_throughput(n=16384, shard_counts=(1, 4),
     return rows
 
 
+def _pad_ratio(spec, batch) -> float:
+    """Share of the rows one ingest of ``batch`` dispatches that are
+    padding, from the ``repro.sketch.trace`` partition counters of one
+    partition round, taken apart from any timed region."""
+    from repro.sketch import trace
+    from repro.sketch.ingest import _partition_stack
+    trace.reset()
+    trace.enable()
+    try:
+        _partition_stack(spec, batch)
+    finally:
+        trace.disable()
+    c = trace.snapshot()["counts"]
+    trace.reset()
+    return c["ingest.pad_rows"] / (c["ingest.rows"] + c["ingest.pad_rows"])
+
+
 def skewed_ingest_throughput(n=16384, n_shards=4, zipf_a=1.5,
                              heat_threshold=0.05, vocab=4096):
     """Skew-aware routing A/B on a Zipf-skewed stream (DESIGN.md §13):
@@ -200,10 +217,11 @@ def skewed_ingest_throughput(n=16384, n_shards=4, zipf_a=1.5,
         each across all ``S`` replica shards by the salted ``(src, dst)``
         hash — the leveled partition buckets ~2x smaller.
 
-    Each row also carries the ``PARTITION_STATS`` load counters for its
-    own partition rounds (max/mean bucket fill, pad ratio) and
-    ``mean_rel_err``: the mean |est - truth| / truth of hot-key edge
-    queries on a small *identical-memory* sketch fed the same stream both
+    Each row also carries ``pad_ratio``, the share of dispatched rows that
+    are padding, from the ``repro.sketch.trace`` partition counters of its
+    own partition round, and ``mean_rel_err``: the mean
+    |est - truth| / truth of hot-key edge queries on a small
+    *identical-memory* sketch fed the same stream both
     ways — splitting gives the head vertex's neighbors ``S``x the
     candidate rows and pool headroom at unchanged total bytes, so the
     routed error is strictly lower (gated same-run by check_bench.py,
@@ -213,7 +231,6 @@ def skewed_ingest_throughput(n=16384, n_shards=4, zipf_a=1.5,
     """
     from repro import sketch as skt
     from repro.data.tokens import zipf_unigram
-    from repro.telemetry.stream_stats import PARTITION_STATS
 
     rng = np.random.default_rng(0)
     p = zipf_unigram(vocab, zipf_a)
@@ -239,16 +256,11 @@ def skewed_ingest_throughput(n=16384, n_shards=4, zipf_a=1.5,
     warmup, iters = 1, 5
     states = {tag: [skt.create(spec) for _ in range(warmup + iters)]
               for tag, _ in variants}
-    snaps = {tag: [] for tag, _ in variants}
+    pad_ratio = {tag: _pad_ratio(sp, batch) for tag, sp in variants}
 
     def run(tag, sp):
-        # per-call reset/snapshot: the variants alternate inside
-        # _timed_medians, so the global accumulator must be scoped to
-        # exactly this call's partition round
-        PARTITION_STATS.reset()
         st = skt.ingest(sp, states[tag].pop(), batch, path="scan")
         jax.block_until_ready(st.shards.C)
-        snaps[tag].append(PARTITION_STATS.snapshot())
         return st
 
     medians = _timed_medians(
@@ -286,22 +298,20 @@ def skewed_ingest_throughput(n=16384, n_shards=4, zipf_a=1.5,
     rows, result = [], {}
     for tag, sp in variants:
         dt = medians[tag]
-        snap = snaps[tag][-1]  # per-call scoped: any round is the round
         rows.append([f"{tag}_x{n_shards}", n, n_shards,
                      len(sp.routing.splits) if sp.routing else 0,
-                     f"{snap['max_fill']:.3f}", f"{snap['pad_ratio']:.3f}",
+                     f"{pad_ratio[tag]:.3f}",
                      f"{mean_rel_err[tag]:.4f}",
                      f"{dt / n * 1e6:.3f}", f"{dt:.3f}"])
         result[f"{tag}_x{n_shards}"] = {
             "edges": n, "shards": n_shards, "zipf_a": zipf_a,
             "split_keys": len(sp.routing.splits) if sp.routing else 0,
-            "max_fill": snap["max_fill"], "mean_fill": snap["mean_fill"],
-            "pad_ratio": snap["pad_ratio"], "imbalance": snap["imbalance"],
+            "pad_ratio": pad_ratio[tag],
             "mean_rel_err": mean_rel_err[tag],
             "us_per_edge": dt / n * 1e6, "total_s": dt}
     write_csv("skewed_ingest_throughput",
-              ["impl", "edges", "shards", "split_keys", "max_fill",
-               "pad_ratio", "mean_rel_err", "us_per_edge", "total_s"], rows)
+              ["impl", "edges", "shards", "split_keys", "pad_ratio",
+               "mean_rel_err", "us_per_edge", "total_s"], rows)
     _merge_bench(result)
     return rows
 
@@ -987,7 +997,7 @@ def main(argv=None):
         for r in xrows:
             print(",".join(str(x) for x in r))
         krows = skewed_ingest_throughput()
-        print("impl,edges,shards,split_keys,max_fill,pad_ratio,"
+        print("impl,edges,shards,split_keys,pad_ratio,"
               "mean_rel_err,us_per_edge,total_s")
         for r in krows:
             print(",".join(str(x) for x in r))
@@ -1007,7 +1017,7 @@ def main(argv=None):
     for r in srows:
         print(",".join(str(x) for x in r))
     krows = skewed_ingest_throughput()
-    print("impl,edges,shards,split_keys,max_fill,pad_ratio,mean_rel_err,"
+    print("impl,edges,shards,split_keys,pad_ratio,mean_rel_err,"
           "us_per_edge,total_s")
     for r in krows:
         print(",".join(str(x) for x in r))

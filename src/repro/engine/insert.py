@@ -240,52 +240,63 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
     over the shard axis (property-tested in tests/test_sketch_api.py).
     """
     TRACE_COUNTS["stacked"] += 1  # trace-time side effect (compile counter)
+    # named scopes (``ingest.*``) tag every instruction's op_name metadata,
+    # so a device trace can put each operation on the step that made it;
+    # they add metadata only, never an instruction
     S, B = batch.src.shape
-    valid = jnp.arange(B, dtype=jnp.int32)[None, :] \
-        < jnp.asarray(n_valid, jnp.int32)[:, None]
+    with jax.named_scope("ingest.plan"):
+        valid = jnp.arange(B, dtype=jnp.int32)[None, :] \
+            < jnp.asarray(n_valid, jnp.int32)[:, None]
 
-    ring = WindowRing.for_config(cfg)
-    widx = (batch.time.astype(jnp.int32)
-            // jnp.int32(cfg.subwindow_size)).astype(jnp.int32)
-    plan = jax.vmap(ring.plan)(states.slot_widx, states.cur_widx, widx, valid)
+        ring = WindowRing.for_config(cfg)
+        widx = (batch.time.astype(jnp.int32)
+                // jnp.int32(cfg.subwindow_size)).astype(jnp.int32)
+        plan = jax.vmap(ring.plan)(states.slot_widx, states.cur_widx, widx,
+                                   valid)
 
     # apply the plan per shard: zero re-claimed slot planes, commit ring
-    zero = lambda arr, axis: jax.vmap(
-        lambda a, r: WindowRing.zero_reset_slots(a, axis, r))(arr, plan.reset)
-    states = LSketchState(
-        key=states.key, C=zero(states.C, 3), P=zero(states.P, 3),
-        pool_key=states.pool_key, pool_C=zero(states.pool_C, 1),
-        pool_P=zero(states.pool_P, 1), pool_lost=states.pool_lost,
-        slot_widx=plan.slot_widx, cur_widx=plan.cur_widx)
+    with jax.named_scope("ingest.reclaim"):
+        zero = lambda arr, axis: jax.vmap(
+            lambda a, r: WindowRing.zero_reset_slots(a, axis, r))(
+                arr, plan.reset)
+        states = LSketchState(
+            key=states.key, C=zero(states.C, 3), P=zero(states.P, 3),
+            pool_key=states.pool_key, pool_C=zero(states.pool_C, 1),
+            pool_P=zero(states.pool_P, 1), pool_lost=states.pool_lost,
+            slot_widx=plan.slot_widx, cur_widx=plan.cur_widx)
 
-    # addressing is vectorized over any batch shape — feed [S, B] directly
-    pa = precompute(cfg, batch.src, batch.src_label)
-    pb = precompute(cfg, batch.dst, batch.dst_label)
-    probes = edge_probes(cfg, pa, pb)
-    le_idx = hsh.edge_label_bucket(batch.edge_label, cfg.c, cfg.seed)
-    w = batch.weight.astype(states.C.dtype)
-    w_count = w * plan.count_live.astype(w.dtype)
-    w_key = w * plan.key_live.astype(w.dtype)
+    with jax.named_scope("ingest.plan"):
+        # addressing is vectorized over any batch shape — feed [S, B]
+        pa = precompute(cfg, batch.src, batch.src_label)
+        pb = precompute(cfg, batch.dst, batch.dst_label)
+        probes = edge_probes(cfg, pa, pb)
+        le_idx = hsh.edge_label_bucket(batch.edge_label, cfg.c, cfg.seed)
+        w = batch.weight.astype(states.C.dtype)
+        w_count = w * plan.count_live.astype(w.dtype)
+        w_key = w * plan.key_live.astype(w.dtype)
 
     def scan_path(st):
-        return jax.vmap(
-            lambda s_st, s_pr, s_le, s_sl, s_wc, s_wk, s_v: _scan_insert(
-                cfg, s_st, s_pr, s_le, s_sl, s_wc, s_wk, s_v)
-        )(st, probes, le_idx, plan.slot, w_count, w_key, valid)
+        with jax.named_scope("ingest.scan"):
+            return jax.vmap(
+                lambda s_st, s_pr, s_le, s_sl, s_wc, s_wk, s_v: _scan_insert(
+                    cfg, s_st, s_pr, s_le, s_sl, s_wc, s_wk, s_v)
+            )(st, probes, le_idx, plan.slot, w_count, w_key, valid)
 
     # single-segment test: every shard's valid prefix is one subwindow.
     # Gates the sharded kernel (each shard's items then share
     # plan.slot[s, 0] and count_live == key_live — the kernel's contract,
     # shard by shard) and the delta record (all writes land in one slot).
     if use_pallas or emit_delta:
-        one_segment_rows = jax.vmap(
-            lambda wdx, v: _segment_count(jnp.where(v, wdx, wdx[0])))(
-                widx, valid) == jnp.int32(1)
-        one_segment_all = jnp.all(one_segment_rows)
+        with jax.named_scope("ingest.plan"):
+            one_segment_rows = jax.vmap(
+                lambda wdx, v: _segment_count(jnp.where(v, wdx, wdx[0])))(
+                    widx, valid) == jnp.int32(1)
+            one_segment_all = jnp.all(one_segment_rows)
 
     touched = plan.slot[:, 0]
     if emit_delta:
-        pre = _touched_slot_slices(states, touched)
+        with jax.named_scope("ingest.delta"):
+            pre = _touched_slot_slices(states, touched)
 
     if not use_pallas:
         out = scan_path(states)
@@ -294,22 +305,25 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
             matrix_insert_binned_sharded
 
         def pallas_path(st):
-            return matrix_insert_binned_sharded(
-                cfg, st, probes, le_idx, w_count, touched, max_bin=B)
+            with jax.named_scope("ingest.walk"):
+                return matrix_insert_binned_sharded(
+                    cfg, st, probes, le_idx, w_count, touched, max_bin=B)
 
         out = jax.lax.cond(one_segment_all, pallas_path, scan_path, states)
 
     if not emit_delta:
         return out
-    post = _touched_slot_slices(out, touched)
-    # per row: no reset <=> that row's ring is unchanged (a cur_widx
-    # advance implies a reset), so its every-horizon validity mask is
-    # unchanged and its slot increment is the exact planes delta. The
-    # AND over window-coupled rows is the caller's (tenant groups differ)
-    ok = one_segment_rows & ~jnp.any(plan.reset, axis=1)
-    delta = PlanesDelta(ok=ok, slot=touched,
-                        d_c=post[0] - pre[0], d_p=post[1] - pre[1],
-                        d_pool_c=post[2] - pre[2], d_pool_p=post[3] - pre[3])
+    with jax.named_scope("ingest.delta"):
+        post = _touched_slot_slices(out, touched)
+        # per row: no reset <=> that row's ring is unchanged (a cur_widx
+        # advance implies a reset), so its every-horizon validity mask is
+        # unchanged and its slot increment is the exact planes delta. The
+        # AND over window-coupled rows is the caller's (tenant groups
+        # differ)
+        ok = one_segment_rows & ~jnp.any(plan.reset, axis=1)
+        delta = PlanesDelta(
+            ok=ok, slot=touched, d_c=post[0] - pre[0], d_p=post[1] - pre[1],
+            d_pool_c=post[2] - pre[2], d_pool_p=post[3] - pre[3])
     return out, delta
 
 

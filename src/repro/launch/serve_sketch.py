@@ -44,6 +44,7 @@ import jax
 import numpy as np
 
 from repro import sketch as skt
+from repro.sketch import trace
 from repro.core import LGSConfig, LSketchConfig
 from repro.core.gss import gss_config
 from repro.data.stream import PHONE, edge_batches, generate
@@ -213,6 +214,9 @@ class SketchServer:
 
     # ---- ingest ----
     def ingest(self, batch, tenant=None) -> None:
+        """Submit one batch (a ``server.ingest`` span while
+        ``repro.sketch.trace`` is on: the dispatch of the batch staged
+        before it, this batch's partition, and the plane prewarm)."""
         if self.pool is not None:
             if tenant is None:
                 raise ValueError("pool-mode ingest needs tenant=")
@@ -220,10 +224,11 @@ class SketchServer:
             return
         if tenant is not None:
             raise ValueError("tenant= needs a pool-mode server (pool=)")
-        self._ingestor.submit(batch)
-        if not self.pipeline:
-            self._ingestor.flush()
-        self._prewarm_many([None])
+        with trace.span("server.ingest"):
+            self._ingestor.submit(batch)
+            if not self.pipeline:
+                self._ingestor.flush()
+            self._prewarm_many([None])
 
     def ingest_many(self, batches) -> None:
         """One cross-tenant ingest round (pool mode): ``{tenant: batch}``
@@ -273,28 +278,29 @@ class SketchServer:
         registered sweep falls back to the plain per-horizon prewarm."""
         if not self.prewarm:
             return
-        path = skt.resolve_query_path(self.spec, self.query_path)
-        if path == "scan":
-            return
-        want = list(dict.fromkeys(lasts))
-        for h in self.horizons:
-            if h not in want:
-                want.append(h)
-        if not want:
-            return
-        if len(want) == 1:
-            self._prewarm(want[0], handle=handle)
-            return
-        if self.pool is not None:
-            h0 = handle if handle is not None else self.pool.dispatched
+        with trace.span("planes.prewarm"):
+            path = skt.resolve_query_path(self.spec, self.query_path)
+            if path == "scan":
+                return
+            want = list(dict.fromkeys(lasts))
+            for h in self.horizons:
+                if h not in want:
+                    want.append(h)
+            if not want:
+                return
+            if len(want) == 1:
+                self._prewarm(want[0], handle=handle)
+                return
+            if self.pool is not None:
+                h0 = handle if handle is not None else self.pool.dispatched
+                skt.query_planes_multi(self.spec, h0, want,
+                                       groups=self.pool.n_slots)
+                return
+            h0 = handle if handle is not None else self._ingestor.dispatched
+            if h0 is None:
+                return
             skt.query_planes_multi(self.spec, h0, want,
-                                   groups=self.pool.n_slots)
-            return
-        h0 = handle if handle is not None else self._ingestor.dispatched
-        if h0 is None:
-            return
-        skt.query_planes_multi(self.spec, h0, want,
-                               collective=(path == "collective"))
+                                   collective=(path == "collective"))
 
     def serving_summary(self, alpha: float = 0.5) -> str:
         """One-line serving-health summary for periodic operator logging:

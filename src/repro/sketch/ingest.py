@@ -60,6 +60,7 @@ from .spec import SketchSpec
 from .routing import HeavyKeyDetector, routed_assignment
 from .state import ShardedState, create, mesh_context, with_mesh
 from . import query as _query
+from . import trace
 
 _FIELDS = ("src", "dst", "src_label", "dst_label", "edge_label", "weight",
            "time")
@@ -124,30 +125,49 @@ def _partition_stack(spec: SketchSpec, batch: EdgeBatch):
     Routing-aware (DESIGN.md §13): a spec carrying a ``RoutingTable``
     scatters split hot keys over their replica shards via the salted
     ``(src, dst)`` hash; without one, ``routed_assignment`` degenerates
-    to the plain endpoint hash bit-for-bit. Every round's shard counts
-    feed the process-wide ``telemetry.stream_stats.PARTITION_STATS``
-    load-imbalance counters (max/mean bucket fill, pad ratio).
+    to the plain endpoint hash bit-for-bit. While ``repro.sketch.trace``
+    is on, each call is an ``ingest.partition`` span and, after it, adds
+    to the ``ingest.batches``, ``ingest.rows``, ``ingest.pad_rows``
+    (padding dispatched, ``n_shards * L - rows``) and
+    ``ingest.boundary_batches`` (some shard's valid rows span several
+    subwindows, so the stacked insert takes the scan, not the key walk)
+    counters.
     """
-    from repro.telemetry.stream_stats import PARTITION_STATS
-    fields = {f: np.asarray(getattr(batch, f)) for f in _FIELDS}
-    sid = routed_assignment(spec, fields["src"], fields["dst"],
-                            fields["src_label"])
-    n_sh = spec.n_shards
-    index = [np.flatnonzero(sid == s) for s in range(n_sh)]
-    counts = np.array([len(ix) for ix in index], np.int32)
-    L = _shard_bucket(max(int(counts.max()), 1), floor=64)
-    PARTITION_STATS.record(counts, L)
-    out = {f: np.zeros((n_sh, L), np.int32) for f in _FIELDS}
-    for s, ix in enumerate(index):
-        m = len(ix)
-        if m == 0:
-            continue  # all-zero row, fully masked by n_valid == 0
-        for f in _FIELDS:
-            row = out[f][s]
-            row[:m] = fields[f][ix]
-            row[m:] = row[m - 1]  # replicate-last keeps time non-decreasing
-    stacked = EdgeBatch(**{f: jnp.asarray(out[f]) for f in _FIELDS})
+    with trace.span("ingest.partition"):
+        fields = {f: np.asarray(getattr(batch, f)) for f in _FIELDS}
+        sid = routed_assignment(spec, fields["src"], fields["dst"],
+                                fields["src_label"])
+        n_sh = spec.n_shards
+        index = [np.flatnonzero(sid == s) for s in range(n_sh)]
+        counts = np.array([len(ix) for ix in index], np.int32)
+        L = _shard_bucket(max(int(counts.max()), 1), floor=64)
+        out = {f: np.zeros((n_sh, L), np.int32) for f in _FIELDS}
+        for s, ix in enumerate(index):
+            m = len(ix)
+            if m == 0:
+                continue  # all-zero row, fully masked by n_valid == 0
+            for f in _FIELDS:
+                row = out[f][s]
+                row[:m] = fields[f][ix]
+                row[m:] = row[m - 1]  # replicate-last: time non-decreasing
+        stacked = EdgeBatch(**{f: jnp.asarray(out[f]) for f in _FIELDS})
+    if trace.enabled():
+        _count_partition(spec, out["time"], counts)
     return stacked, jnp.asarray(counts)
+
+
+def _count_partition(spec: SketchSpec, time, counts) -> None:
+    """The partition's counters (see ``_partition_stack``) from the
+    stacked ``time`` rows: a row's padding repeats its last valid row, so
+    a subwindow change between neighbours lies in the valid rows, as the
+    device's ``one_segment_all`` test counts it."""
+    rows = int(counts.sum())
+    widx = time // spec.config.subwindow_size
+    trace.count("ingest.batches")
+    trace.count("ingest.rows", rows)
+    trace.count("ingest.pad_rows", time.size - rows)
+    trace.count("ingest.boundary_batches",
+                int(np.any(widx[:, 1:] != widx[:, :-1])))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),
@@ -198,7 +218,8 @@ def _dispatch_stacked(spec: SketchSpec, state: ShardedState, stacked,
     ``ingest`` and ``AsyncIngestor``); donates the input handle. A
     mesh-resident handle (``place``) keeps its residency: the partition is
     placed under the same shard-axis sharding and the new handle carries
-    the MeshContext forward.
+    the MeshContext forward. An ``ingest.dispatch`` span while
+    ``repro.sketch.trace`` is on: the host's time to enqueue.
 
     Plane propagation (DESIGN.md §10): when the consumed handle carries
     cached ``QueryPlanes`` (or an unresolved delta chain), the dispatch
@@ -206,27 +227,29 @@ def _dispatch_stacked(spec: SketchSpec, state: ShardedState, stacked,
     ``(parent planes, chain)`` off the fresh handle, so the next query
     can delta-apply instead of rebuilding. The emission flag is static —
     a handle that was never queried ingests with zero delta overhead."""
-    ctx = mesh_context(state)
-    local = ctx if ctx is not None and ctx.divides(spec.n_shards) else None
-    if local is not None:
-        stacked, n_valid = _place_partition(ctx, stacked, n_valid)
-    delta = carry = None
-    if spec.kind == "lgs":
-        shards = _ingest_stacked_lgs(spec.config.key(), state.shards,
-                                     stacked, n_valid)
-    else:
-        path = eng_insert.resolve_path(spec.config, path)
-        if path == "chunked":
-            raise ValueError("the stacked ingest has no chunked path")
-        carry = _query.planes_delta_base(state)
-        out = _ingest_stacked_lsketch(
-            spec.config, local, state.shards, stacked, n_valid,
-            use_pallas=path == "pallas", emit_delta=carry is not None)
-        shards, delta = out if carry is not None else (out, None)
-    new = with_mesh(ShardedState(shards=shards), ctx)
-    if carry is not None:
-        _query.attach_planes_delta(new, carry[0], carry[1], delta)
-    return new
+    with trace.span("ingest.dispatch"):
+        ctx = mesh_context(state)
+        local = ctx if ctx is not None and ctx.divides(spec.n_shards) \
+            else None
+        if local is not None:
+            stacked, n_valid = _place_partition(ctx, stacked, n_valid)
+        delta = carry = None
+        if spec.kind == "lgs":
+            shards = _ingest_stacked_lgs(spec.config.key(), state.shards,
+                                         stacked, n_valid)
+        else:
+            path = eng_insert.resolve_path(spec.config, path)
+            if path == "chunked":
+                raise ValueError("the stacked ingest has no chunked path")
+            carry = _query.planes_delta_base(state)
+            out = _ingest_stacked_lsketch(
+                spec.config, local, state.shards, stacked, n_valid,
+                use_pallas=path == "pallas", emit_delta=carry is not None)
+            shards, delta = out if carry is not None else (out, None)
+        new = with_mesh(ShardedState(shards=shards), ctx)
+        if carry is not None:
+            _query.attach_planes_delta(new, carry[0], carry[1], delta)
+        return new
 
 
 def ingest(spec: SketchSpec, state: ShardedState, batch: EdgeBatch,

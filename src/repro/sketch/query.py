@@ -72,6 +72,7 @@ from repro.kernels import use_tpu_kernels
 
 from .spec import SketchSpec
 from .state import ShardedState, mesh_context
+from . import trace
 
 # trace-time counters keyed by (kind, path) — tests assert one jitted
 # program per (kind, bucket, path) by reading these before/after a
@@ -494,14 +495,17 @@ def _resolve_pending(state, ckey, apply_one):
     for d in deltas:
         # one device read per link, paid on the first query of the handle
         # (which was about to block on the flush results anyway)
-        if not bool(jnp.all(d.ok)):
+        with trace.span("planes.wait"):
+            ok = bool(jnp.all(d.ok))
+        if not ok:
             object.__setattr__(state, _PENDING_ATTR, None)
             return None
     planes = base[ckey]
     # all links ok => the ring never moved across the chain, so every
     # link's mask equals the final state's — apply them all under it
-    for d in deltas:
-        planes = apply_one(planes, d)
+    with trace.span("planes.fold"):
+        for d in deltas:
+            planes = apply_one(planes, d)
     PLANES_BUILD_COUNTS["delta"] += 1
     return planes
 
@@ -607,15 +611,16 @@ def query_planes(spec: SketchSpec, state, last=None, *,
         planes = _resolve_pending(state, ckey, apply_one)
     if planes is None:
         PLANES_BUILD_COUNTS["build"] += 1
-        if collective:
-            ctx = _collective_ctx(spec, state)
-            planes = _build_planes_collective(
-                spec, ctx.mesh, ctx.axis, state.shards, horizon=horizon)
-        else:
-            stacked = isinstance(state, ShardedState)
-            shards = state.shards if stacked else state
-            planes = _build_planes(spec, shards, horizon=horizon,
-                                   stacked=stacked, groups=groups)
+        with trace.span("planes.build"):
+            if collective:
+                ctx = _collective_ctx(spec, state)
+                planes = _build_planes_collective(
+                    spec, ctx.mesh, ctx.axis, state.shards, horizon=horizon)
+            else:
+                stacked = isinstance(state, ShardedState)
+                shards = state.shards if stacked else state
+                planes = _build_planes(spec, shards, horizon=horizon,
+                                       stacked=stacked, groups=groups)
     _cache_put(cache, ckey, planes)
     return planes
 
@@ -673,15 +678,16 @@ def query_planes_multi(spec: SketchSpec, state, lasts, *,
     planes = _resolve_pending(state, ckey, apply_one)
     if planes is None:
         PLANES_BUILD_COUNTS["build"] += 1
-        if collective:
-            ctx = _collective_ctx(spec, state)
-            planes = _build_planes_collective_multi(
-                spec, ctx.mesh, ctx.axis, state.shards, horizons=uniq)
-        else:
-            stacked = isinstance(state, ShardedState)
-            shards = state.shards if stacked else state
-            planes = _build_planes_multi(spec, shards, horizons=uniq,
-                                         stacked=stacked, groups=groups)
+        with trace.span("planes.build"):
+            if collective:
+                ctx = _collective_ctx(spec, state)
+                planes = _build_planes_collective_multi(
+                    spec, ctx.mesh, ctx.axis, state.shards, horizons=uniq)
+            else:
+                stacked = isinstance(state, ShardedState)
+                shards = state.shards if stacked else state
+                planes = _build_planes_multi(spec, shards, horizons=uniq,
+                                             stacked=stacked, groups=groups)
     _cache_put(cache, ckey, planes)
     return planes, uniq
 

@@ -4,75 +4,12 @@ Sketches the token-bigram stream of the training corpus: heavy-hitter
 bigrams, per-band volumes, and windowed drift ("did the bigram mix change
 over the last j subwindows?") — data-quality monitoring primitives at
 sub-linear memory, straight from the paper's query set.
-
-Also hosts ``PARTITION_STATS``: the process-wide shard-load accumulator
-the sharded ingest partition feeds (``sketch.ingest._partition_stack``,
-DESIGN.md §13) — max/mean bucket fill and pad ratio per partition round,
-so skew regressions show up in CI bench artifacts instead of silently
-inflating dispatch padding.
 """
 
 from __future__ import annotations
 
-import threading
-
-import numpy as np
-
-
-class PartitionLoadStats:
-    """Per-shard load-imbalance counters over ingest partition rounds.
-
-    Each round contributes its shard counts and pad bucket ``L``:
-
-      * ``max_fill`` / ``mean_fill`` — hottest / average shard count as a
-        fraction of the bucket every shard pads to (max_fill near 1.0 and
-        mean_fill far below it = one hot shard sized the whole dispatch);
-      * ``pad_ratio``  — fraction of dispatched rows that are padding
-        (``1 - sum(counts) / (n_shards * L)``): the direct device-work
-        overhead of imbalance;
-      * ``imbalance``  — max/mean shard count (1.0 = perfectly level).
-
-    ``snapshot()`` averages over the rounds since the last ``reset()``.
-    Thread-safe (serving loops partition from multiple threads); recording
-    is a few scalar ops per round, noise next to the partition itself.
-    """
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.reset()
-
-    def reset(self) -> None:
-        with self._lock:
-            self.rounds = 0
-            self._max_fill = 0.0
-            self._mean_fill = 0.0
-            self._pad_ratio = 0.0
-            self._imbalance = 0.0
-
-    def record(self, counts, bucket: int) -> None:
-        counts = np.asarray(counts, np.float64)
-        n_sh = counts.shape[0]
-        mx, mean = float(counts.max()), float(counts.mean())
-        with self._lock:
-            self.rounds += 1
-            self._max_fill += mx / bucket
-            self._mean_fill += mean / bucket
-            self._pad_ratio += 1.0 - float(counts.sum()) / (n_sh * bucket)
-            self._imbalance += mx / max(mean, 1e-9)
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            n = max(self.rounds, 1)
-            return {"rounds": self.rounds,
-                    "max_fill": self._max_fill / n,
-                    "mean_fill": self._mean_fill / n,
-                    "pad_ratio": self._pad_ratio / n,
-                    "imbalance": self._imbalance / n}
-
-
-PARTITION_STATS = PartitionLoadStats()
-
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import EdgeBatch, LSketch, LSketchConfig, insert_batch
 from repro.data.tokens import DEFAULT_BAND_VOCAB, bigram_stream, token_band

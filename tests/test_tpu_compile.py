@@ -95,7 +95,24 @@ def test_ingest_program_compiles_with_walk_kernel(chip, tpu_lowering):
     compiled = _compile(_ingest_stacked_lsketch, CFG, None, _state(chip),
                         batch, _i32(chip, 1), use_pallas=True,
                         emit_delta=True)
-    assert "sketch_insert_walk" in compiled.as_text()
+    text = compiled.as_text()
+    assert "sketch_insert_walk" in text
+    # the named scopes reach the compiled program's op_name metadata
+    for scope in ("plan", "reclaim", "walk", "scan", "delta"):
+        assert f"/ingest.{scope}/" in text, scope
+
+
+def test_ingest_scopes_add_metadata_only(chip, tpu_lowering):
+    """The walk program compiles to the same instructions with its
+    ``ingest.*`` scopes and without them (``python -m
+    repro.launch.scope_identity`` checks every variant at the served
+    cell's 36 ring slots)."""
+    from repro.launch import scope_identity as si
+    on, off = (si.compiled_text(CFG, chip, True, True, scopes=s)
+               for s in (True, False))
+    assert si.SCOPE.search(on) and not si.SCOPE.search(off)
+    assert si.canonical(on) == si.canonical(off)
+    assert si.instructions(si.canonical(on)) > 1000
 
 
 def test_edge_query_program_compiles(chip):
