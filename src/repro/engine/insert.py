@@ -13,13 +13,15 @@ and a dead host-device sync per boundary. This module replaces that with a
   3. one ``lax.scan`` walks the time-ordered batch in stream order with the
      paper's exact first-fit probe semantics, each item writing its own
      ring slot — so a batch spanning any number of subwindows is one scan;
-  4. when the batch sits in a single subwindow (the overwhelmingly common
-     case for a real ingest loop) and the sketch uses uniform blocking, the
+  4. when every valid item's counters survive the batch (no item's ring
+     slot is re-claimed by a later subwindow of the same batch: the
+     ``walk_eligible`` rule) and the sketch uses uniform blocking, the
      matrix insert is routed to the block-binned path
      (``kernels/sketch_insert``: the Pallas key walk on TPU, its XLA
-     lowering elsewhere) — the default on TPU; the scan path is the CPU
-     default and the only path for skewed blocking or multi-subwindow
-     batches.
+     lowering elsewhere), each item adding at its own ring slot — the
+     default on TPU, for batches in one subwindow or spanning several; the
+     scan path is the CPU default, the only path for skewed blocking, and
+     the fallback for batches that re-claim a slot.
 
 Host entry point: ``insert_batch(cfg, state, batch, path=...)`` — pads the
 batch to a size bucket (compile-count stays O(log max_batch), padding rows
@@ -45,7 +47,7 @@ from repro.core.queries import PlanesDelta
 from repro.core.types import EMPTY, EdgeBatch, LSketchConfig, LSketchState
 from repro.kernels import use_tpu_kernels
 
-from .window import WindowRing, pad_to_bucket
+from .window import SegmentPlan, WindowRing, pad_to_bucket
 
 # trace-time counters keyed by path name — tests assert single-compile
 # behaviour (one trace per (cfg, batch-shape), zero traces per extra
@@ -59,6 +61,33 @@ def _segment_count(widx):
     if widx.shape[0] <= 1:
         return jnp.int32(widx.shape[0])
     return jnp.int32(1) + jnp.sum((widx[1:] != widx[:-1]).astype(jnp.int32))
+
+
+def ring_plan(cfg: LSketchConfig, slot_widx, cur_widx, time, n_valid):
+    """The ring plan of one shard row of a padded, time-ordered batch:
+    ``(valid, widx, plan)``. Rows >= ``n_valid`` are padding. Shared by
+    both fused entry points (the stacked one vmaps it over its rows)."""
+    valid = jnp.arange(time.shape[0], dtype=jnp.int32) \
+        < jnp.asarray(n_valid, jnp.int32)
+    widx = (time.astype(jnp.int32)
+            // jnp.int32(cfg.subwindow_size)).astype(jnp.int32)
+    plan = WindowRing.for_config(cfg).plan(slot_widx, cur_widx, widx,
+                                           valid=valid)
+    return valid, widx, plan
+
+
+def walk_eligible(plan: SegmentPlan, valid) -> jax.Array:
+    """The one rule for taking the binned walk (both fused entry points):
+    every valid item is count-live, over one shard row or a whole stack.
+
+    Then every valid item is key-live too, the walk's weight-gated claims
+    equal the scan's ``valid``-gated ones, and each item's counters land
+    at its own ``plan.slot`` as the scan adds them (the walk never reads
+    a slot). For a time-ordered stream it fails only when a row holds two
+    subwindows of one ring slot, so the later one re-claims it inside the
+    batch; a late item (older than its slot's owner) fails it as well.
+    """
+    return jnp.all(plan.count_live == valid)
 
 
 def _scan_insert(cfg: LSketchConfig, state: LSketchState, probes, le_idx,
@@ -134,12 +163,8 @@ def insert_batch_fused_impl(cfg: LSketchConfig, state: LSketchState,
     B = batch.src.shape[0]
     if B == 0:
         return state
-    valid = jnp.arange(B, dtype=jnp.int32) < jnp.asarray(n_valid, jnp.int32)
-
-    ring = WindowRing.for_config(cfg)
-    widx = (batch.time.astype(jnp.int32)
-            // jnp.int32(cfg.subwindow_size)).astype(jnp.int32)
-    plan = ring.plan(state.slot_widx, state.cur_widx, widx, valid=valid)
+    valid, _, plan = ring_plan(cfg, state.slot_widx, state.cur_widx,
+                               batch.time, n_valid)
 
     # apply the plan: zero re-claimed slot planes, commit ring bookkeeping
     C = WindowRing.zero_reset_slots(state.C, 3, plan.reset)
@@ -166,18 +191,16 @@ def insert_batch_fused_impl(cfg: LSketchConfig, state: LSketchState,
     if not use_pallas:
         return scan_path(state)
 
-    # Pallas fast path: eligible iff the (valid prefix of the) batch sits in
-    # one subwindow — then every item shares plan.slot[0] and
-    # count_live == key_live, which is exactly the kernel's contract.
+    # binned fast path under the walk_eligible rule; the scan is the
+    # in-dispatch fallback for in-batch slot re-claims
     from repro.kernels.sketch_insert.ops import matrix_insert_binned
 
     def pallas_path(st):
         return matrix_insert_binned(cfg, st, probes, le_idx, w_count,
-                                    plan.slot[0], max_bin=B)
+                                    plan.slot, max_bin=B)
 
-    one_segment = _segment_count(
-        jnp.where(valid, widx, widx[0])) == jnp.int32(1)
-    return jax.lax.cond(one_segment, pallas_path, scan_path, state)
+    return jax.lax.cond(walk_eligible(plan, valid), pallas_path, scan_path,
+                        state)
 
 
 _insert_batch_fused = functools.partial(
@@ -217,12 +240,14 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
 
     Path choice mirrors the single-shard fused path, lifted to the stack:
     the ring plan and addressing are computed for all shards vectorized;
-    when **every** shard's valid prefix sits in a single subwindow (the
-    overwhelmingly common serving case — and always true for GSS) the
-    matrix insert is one shard-axis Pallas launch
+    when ``walk_eligible`` holds on **every** shard row (no row re-claims
+    a ring slot inside the batch: every single-subwindow batch, and any
+    whose rows each span at most ``k`` subwindows; always for GSS) the matrix
+    insert is one shard-axis Pallas launch
     (``matrix_insert_binned_sharded``, grid (n_shards, n_blocks,
-    n_blocks)); otherwise a vmapped ``lax.scan`` replays each shard in
-    stream order. Both live under one ``lax.cond`` in one jitted dispatch.
+    n_blocks)), each edge adding at its own ring slot; otherwise a
+    vmapped ``lax.scan`` replays each shard in stream order. Both live
+    under one ``lax.cond`` in one jitted dispatch.
 
     With ``emit_delta`` (static) the return value is ``(states, delta)``
     where ``delta`` is the ``core.queries.PlanesDelta`` of this flush —
@@ -243,16 +268,10 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
     # named scopes (``ingest.*``) tag every instruction's op_name metadata,
     # so a device trace can put each operation on the step that made it;
     # they add metadata only, never an instruction
-    S, B = batch.src.shape
+    B = batch.src.shape[1]
     with jax.named_scope("ingest.plan"):
-        valid = jnp.arange(B, dtype=jnp.int32)[None, :] \
-            < jnp.asarray(n_valid, jnp.int32)[:, None]
-
-        ring = WindowRing.for_config(cfg)
-        widx = (batch.time.astype(jnp.int32)
-                // jnp.int32(cfg.subwindow_size)).astype(jnp.int32)
-        plan = jax.vmap(ring.plan)(states.slot_widx, states.cur_widx, widx,
-                                   valid)
+        valid, widx, plan = jax.vmap(functools.partial(ring_plan, cfg))(
+            states.slot_widx, states.cur_widx, batch.time, n_valid)
 
     # apply the plan per shard: zero re-claimed slot planes, commit ring
     with jax.named_scope("ingest.reclaim"):
@@ -282,19 +301,15 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
                     cfg, s_st, s_pr, s_le, s_sl, s_wc, s_wk, s_v)
             )(st, probes, le_idx, plan.slot, w_count, w_key, valid)
 
-    # single-segment test: every shard's valid prefix is one subwindow.
-    # Gates the sharded kernel (each shard's items then share
-    # plan.slot[s, 0] and count_live == key_live — the kernel's contract,
-    # shard by shard) and the delta record (all writes land in one slot).
-    if use_pallas or emit_delta:
+    # single-segment test per row: the row's valid prefix is one
+    # subwindow, so all its writes land in one slot — gates the delta
+    # record (the walk's gate is walk_eligible)
+    if emit_delta:
         with jax.named_scope("ingest.plan"):
             one_segment_rows = jax.vmap(
                 lambda wdx, v: _segment_count(jnp.where(v, wdx, wdx[0])))(
                     widx, valid) == jnp.int32(1)
-            one_segment_all = jnp.all(one_segment_rows)
-
-    touched = plan.slot[:, 0]
-    if emit_delta:
+        touched = plan.slot[:, 0]
         with jax.named_scope("ingest.delta"):
             pre = _touched_slot_slices(states, touched)
 
@@ -307,9 +322,11 @@ def insert_stacked_fused_impl(cfg: LSketchConfig, states: LSketchState,
         def pallas_path(st):
             with jax.named_scope("ingest.walk"):
                 return matrix_insert_binned_sharded(
-                    cfg, st, probes, le_idx, w_count, touched, max_bin=B)
+                    cfg, st, probes, le_idx, w_count, plan.slot, max_bin=B)
 
-        out = jax.lax.cond(one_segment_all, pallas_path, scan_path, states)
+        with jax.named_scope("ingest.plan"):
+            take_walk = walk_eligible(plan, valid)
+        out = jax.lax.cond(take_walk, pallas_path, scan_path, states)
 
     if not emit_delta:
         return out
@@ -367,8 +384,9 @@ def insert_batch(cfg: LSketchConfig, state: LSketchState, batch: EdgeBatch,
     """Insert a time-ordered batch in **one** jit dispatch.
 
     path: "auto" (backend default), "scan" (fused lax.scan), "pallas"
-    (fused + block-binned kernel for single-subwindow batches; requires
-    uniform blocking), or "chunked" (legacy host split loop — reference).
+    (fused + block-binned kernel for batches that re-claim no ring slot;
+    requires uniform blocking), or "chunked" (legacy host split loop —
+    reference).
     """
     n = int(batch.src.shape[0])
     if n == 0:

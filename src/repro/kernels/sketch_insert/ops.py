@@ -13,9 +13,12 @@ Pipeline (DESIGN.md §2 "binned batch insertion"):
      original stream order.
 
 ``matrix_insert_binned_sharded`` is the composable middle: pre-addressed
-probes plus each shard's target ring slot, over a ``[n_shards, ...]``
+probes plus each edge's target ring slot, over a ``[n_shards, ...]``
 stack; the engine's stacked insert (the ``repro.sketch`` handle layer)
-routes through it. ``matrix_insert_binned`` is its one-shard case and
+routes through it. The walk never reads a slot (``key`` has no slot
+axis): the slot only picks where steps 5 and 6 add each edge's weight,
+so one launch serves a batch that spans several subwindows.
+``matrix_insert_binned`` is its one-shard case and
 ``insert_window_batch_pallas`` the standalone per-subwindow drop-in kept
 for tests and direct use.
 
@@ -42,7 +45,8 @@ from .kernel import CHUNK, sketch_insert_stream_walk, sketch_insert_walk_kernel
 
 def _pool_pass(cfg: LSketchConfig, state: LSketchState, slot, probes, le_idx,
                weight, failed) -> LSketchState:
-    """Additional-pool insertion for edges the matrix rejected (stream order).
+    """Additional-pool insertion for edges the matrix rejected (stream order),
+    each at its own ring slot (``slot`` [B]).
 
     The walk visits only the failed items: a stable sort puts them first
     (stream order preserved among them — non-failed items are provable
@@ -72,8 +76,8 @@ def _pool_pass(cfg: LSketchConfig, state: LSketchState, slot, probes, le_idx,
         pool_key = pool_key.at[pslot, 1].set(
             jnp.where(pfound, probes.pid_dst[i], pold[1]))
         pw = jnp.where(pfound, w, 0)
-        pool_C = st.pool_C.at[pslot, slot].add(pw)
-        pool_P = st.pool_P.at[pslot, slot, le_idx[i]].add(pw)
+        pool_C = st.pool_C.at[pslot, slot[i]].add(pw)
+        pool_P = st.pool_P.at[pslot, slot[i], le_idx[i]].add(pw)
         lost = st.pool_lost + jnp.where((w > 0) & ~pok.any(), w, 0)
         return idx + 1, LSketchState(
             key=st.key, C=st.C, P=st.P, pool_key=pool_key,
@@ -161,17 +165,19 @@ def _key_walk_pallas(cfg: LSketchConfig, probes: EdgeProbes, weight, key_t,
 def matrix_insert_binned(cfg: LSketchConfig, state: LSketchState,
                          probes: EdgeProbes, le_idx, weight, slot,
                          max_bin: int | None = None) -> LSketchState:
-    """Block-binned insertion of one shard's pre-addressed batch into ring
-    ``slot``: the one-shard case of ``matrix_insert_binned_sharded``.
+    """Block-binned insertion of one shard's pre-addressed batch: the
+    one-shard case of ``matrix_insert_binned_sharded``.
 
     Traced (not jitted) — compose inside a jitted caller. ``weight`` must
     already carry the window-liveness mask (zeros insert nothing and claim
-    nothing); ``slot`` is the (traced) ring slot shared by the whole batch.
+    nothing); ``slot`` is each item's (traced) ring slot, ``[B]``, or one
+    scalar shared by the whole batch.
     """
     lift = lambda x: x[None]
     out = matrix_insert_binned_sharded(
         cfg, jax.tree.map(lift, state), jax.tree.map(lift, probes),
-        le_idx[None], weight[None], jnp.asarray(slot)[None], max_bin=max_bin)
+        le_idx[None], weight[None], jnp.broadcast_to(slot, weight.shape)[None],
+        max_bin=max_bin)
     return jax.tree.map(lambda x: x[0], out)
 
 
@@ -181,18 +187,21 @@ def matrix_insert_binned_sharded(cfg: LSketchConfig, state: LSketchState,
     """Block-binned insertion over a whole ``[n_shards, ...]`` stack.
 
     ``state`` carries a leading ``[n_shards]`` axis on every leaf; probe
-    tensors are ``[n_shards, B, s]``, ``le_idx``/``weight`` are
-    ``[n_shards, B]`` and ``slot`` is ``[n_shards]`` — each shard's own
-    (traced) ring slot. ``weight`` must already carry the per-shard
-    window-liveness **and** ``n_valid`` padding mask (zero-weight rows
-    insert nothing and claim nothing — an all-zero row is how an empty
-    shard stays a strict no-op). Traced (not jitted) — compose inside a
+    tensors are ``[n_shards, B, s]``; ``le_idx``, ``weight`` and ``slot``
+    are ``[n_shards, B]``, ``slot`` each edge's own (traced) ring slot.
+    ``weight`` must already carry the per-shard window-liveness **and**
+    ``n_valid`` padding mask (zero-weight rows insert nothing and claim
+    nothing — an all-zero row is how an empty shard stays a strict
+    no-op). Traced (not jitted) — compose inside a
     jitted caller.
 
     The key walk runs on the Pallas kernel on TPU and on its compiled XLA
     lowering elsewhere; either way the counters (write-only in the walk)
-    land in one scatter-add on the full stacked ``C``/``P``, with no
-    per-slot plane gather or write-back.
+    land in one scatter-add on the full stacked ``C``/``P``, each edge at
+    its own slot, with no per-slot plane gather or write-back. The result
+    equals the stream-order scan whenever every positive-weight edge's
+    counters survive the batch (``count_live == key_live``): claims do not
+    depend on the slot.
     """
     if cfg.block_bounds is not None:
         raise ValueError("the binned insert supports uniform blocking only")
@@ -210,10 +219,9 @@ def matrix_insert_binned_sharded(cfg: LSketchConfig, state: LSketchState,
     cs = (bid0 % jnp.int32(n)) * jnp.int32(b) + v % b
     wm = jnp.where(inserted, weight, 0)
     s_idx = jnp.arange(S, dtype=jnp.int32)[:, None]
-    slot_b = slot[:, None]
     state = LSketchState(key=jnp.moveaxis(new_key_t, 1, 3),
-                         C=state.C.at[s_idx, rs, cs, tzs, slot_b].add(wm),
-                         P=state.P.at[s_idx, rs, cs, tzs, slot_b,
+                         C=state.C.at[s_idx, rs, cs, tzs, slot].add(wm),
+                         P=state.P.at[s_idx, rs, cs, tzs, slot,
                                       le_idx].add(wm),
                          pool_key=state.pool_key,
                          pool_C=state.pool_C, pool_P=state.pool_P,

@@ -12,10 +12,12 @@
      so even an empty shard is a strict no-op);
   3. one jitted dispatch runs the engine's **stacked** fused insert
      (``engine.insert.insert_stacked_fused_impl``) over the whole
-     ``[n_shards, ...]`` stack: on the Pallas path every single-subwindow
-     batch is one shard-axis kernel launch (grid ``(n_shards, n_blocks,
-     n_blocks)``); the vmapped ``lax.scan`` takes multi-subwindow batches
-     inside the same dispatch. The insert path follows the engine's
+     ``[n_shards, ...]`` stack: on the Pallas path every batch that
+     re-claims no ring slot (every batch whose rows each end fewer than
+     ``k`` subwindows after they start) is one shard-axis kernel launch (grid
+     ``(n_shards, n_blocks, n_blocks)``), each edge adding at its own
+     slot; the vmapped ``lax.scan`` takes in-batch slot re-claims inside
+     the same dispatch. The insert path follows the engine's
      selection rule (``engine.insert.resolve_path``): the binned path by
      default on TPU, compiled scan elsewhere.
 
@@ -128,10 +130,11 @@ def _partition_stack(spec: SketchSpec, batch: EdgeBatch):
     to the plain endpoint hash bit-for-bit. While ``repro.sketch.trace``
     is on, each call is an ``ingest.partition`` span and, after it, adds
     to the ``ingest.batches``, ``ingest.rows``, ``ingest.pad_rows``
-    (padding dispatched, ``n_shards * L - rows``) and
+    (padding dispatched, ``n_shards * L - rows``),
     ``ingest.boundary_batches`` (some shard's valid rows span several
-    subwindows, so the stacked insert takes the scan, not the key walk)
-    counters.
+    subwindows, so no plane delta is recorded) and ``ingest.scan_batches``
+    (some shard's valid rows hold two subwindows of one ring slot, so
+    the stacked insert takes the scan, not the key walk) counters.
     """
     with trace.span("ingest.partition"):
         fields = {f: np.asarray(getattr(batch, f)) for f in _FIELDS}
@@ -160,14 +163,21 @@ def _count_partition(spec: SketchSpec, time, counts) -> None:
     """The partition's counters (see ``_partition_stack``) from the
     stacked ``time`` rows: a row's padding repeats its last valid row, so
     a subwindow change between neighbours lies in the valid rows, as the
-    device's ``one_segment_all`` test counts it."""
+    device's single-segment test counts it, and the padding adds no
+    subwindow. A scan batch is one the device's ``walk_eligible`` rule
+    sends to the scan, on a time-ordered stream (the host does not see
+    the ring, so an item older than its slot's owner is not counted)."""
     rows = int(counts.sum())
     widx = time // spec.config.subwindow_size
+    k = spec.config.effective_k
     trace.count("ingest.batches")
     trace.count("ingest.rows", rows)
     trace.count("ingest.pad_rows", time.size - rows)
     trace.count("ingest.boundary_batches",
                 int(np.any(widx[:, 1:] != widx[:, :-1])))
+    reclaim = any(len(np.unique(u % k)) < len(u)
+                  for u in map(np.unique, widx))
+    trace.count("ingest.scan_batches", int(reclaim))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1),

@@ -64,7 +64,7 @@ def test_pallas_binned_matches_reference_single_and_multi():
     batch = _batch(arrays)
     ref = eng_insert.insert_batch_chunked(CFG, init_state(CFG), batch)
     pal = eng_insert.insert_batch(CFG, init_state(CFG), batch, path="pallas")
-    assert _states_equal(ref, pal)  # multi-subwindow: cond falls to scan
+    assert _states_equal(ref, pal)  # 12 subwindows re-claim: the scan
     one = _batch(arrays[:6] + (np.full(len(arrays[0]), 7, np.int32),))
     ref1 = eng_insert.insert_batch_chunked(CFG, init_state(CFG), one)
     pal1 = eng_insert.insert_batch(CFG, init_state(CFG), one, path="pallas")

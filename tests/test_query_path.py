@@ -361,8 +361,8 @@ def test_planes_delta_fallback_on_ring_movement():
     assert q_mod.PLANES_BUILD_COUNTS["delta"] == before["delta"]
     _assert_paths_agree(spec, st_adv, "lsketch", ctx="advance fallback")
 
-    # multi-subwindow batch: the stacked insert takes the scan path and
-    # the delta record is marked invalid -> rebuild
+    # multi-subwindow batch: its writes land in two slots, so the delta
+    # record is marked invalid -> rebuild
     skt.query_planes(spec, st_adv)
     before = dict(q_mod.PLANES_BUILD_COUNTS)
     st_span = skt.ingest(spec, st_adv, _live_batch(seed=58, tlo=2400,

@@ -14,6 +14,8 @@ The contracts this layer must uphold:
   * the object wrappers are shims: same bits as the functional layer.
 """
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -371,22 +373,135 @@ def _parity_case(cfg, arrays, n_shards):
     return pal
 
 
-@pytest.mark.parametrize("n_shards", [1, 4])
-@pytest.mark.parametrize("span", ["single", "multi"])
-def test_sharded_pallas_matches_scan(n_shards, span):
-    """The shard-axis kernel path (single-subwindow launch + in-dispatch
-    scan fallback) is bit-identical to the vmapped fused scan on the same
-    partition — incl. ring wraparound and pool machinery."""
+# one batch's time span per case: CFG's subwindows are 100 units, k = 4;
+# "two" and "k-1" re-claim no slot (the walk), "multi" wraps the ring
+SPAN_TMAX = {"multi": 2500, "two": 200, "k-1": 300}
+
+
+def _span_arrays(span):
     rng = np.random.default_rng(20)
     n = 400
     src = rng.integers(0, 300, n).astype(np.int32)
     dst = rng.integers(0, 300, n).astype(np.int32)
     t = (np.full(n, 7, np.int32) if span == "single"
-         else np.sort(rng.integers(0, 2500, n)).astype(np.int32))
-    arrays = (src, dst, src % 3, dst % 3,
-              rng.integers(0, 5, n).astype(np.int32),
-              rng.integers(1, 4, n).astype(np.int32), t)
-    _parity_case(CFG, arrays, n_shards)
+         else np.sort(rng.integers(0, SPAN_TMAX[span], n)).astype(np.int32))
+    return (src, dst, src % 3, dst % 3,
+            rng.integers(0, 5, n).astype(np.int32),
+            rng.integers(1, 4, n).astype(np.int32), t)
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("span", ["single", "multi", "two", "k-1"])
+def test_sharded_pallas_matches_scan(n_shards, span):
+    """The shard-axis kernel path (one launch for any batch that re-claims
+    no ring slot, each edge at its own slot, + in-dispatch scan fallback)
+    is bit-identical to the vmapped fused scan on the same partition —
+    incl. ring wraparound and pool machinery."""
+    _parity_case(CFG, _span_arrays(span), n_shards)
+
+
+def _boundary_run(case):
+    """``(cfg, [arrays per batch])`` of a time-ordered run whose batches
+    span subwindow boundaries. "wraps": consecutive 250-unit batches (three
+    subwindows each) over five turns of the ring; "overflow": a
+    two-subwindow batch that saturates OVERFLOW_CFG's pool; "reclaim":
+    subwindows 0 and 4 share ring slot 0, so the later re-claims it inside
+    the batch."""
+    if case in SPAN_TMAX:
+        return CFG, [_span_arrays(case)]
+    rng = np.random.default_rng({"wraps": 25, "overflow": 26,
+                                 "reclaim": 27}[case])
+    if case == "wraps":
+        runs = [(250 * j, 250 * (j + 1), 200) for j in range(8)]
+    elif case == "overflow":
+        runs = [(50, 150, 500)]
+    else:
+        runs = [(0, 500, 400)]
+    n_vertices = 40 if case == "overflow" else 300
+    out = []
+    for lo, hi, n in runs:
+        src = rng.integers(0, n_vertices, n).astype(np.int32)
+        dst = rng.integers(0, n_vertices, n).astype(np.int32)
+        t = np.sort(rng.integers(lo, hi, n)).astype(np.int32)
+        t[0], t[-1] = lo, hi - 1
+        out.append((src, dst, src % 3, dst % 3,
+                    rng.integers(0, 4, n).astype(np.int32),
+                    rng.integers(1, 4, n).astype(np.int32), t))
+    return (OVERFLOW_CFG if case == "overflow" else CFG), out
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("case", ["wraps", "overflow", "reclaim"])
+def test_stacked_boundary_batches_pallas_matches_scan(case, n_shards):
+    """Boundary-spanning batches through the stacked pallas path equal the
+    scan bit for bit (state and pool) after every batch: runs of them
+    across ring wraparounds, pool overflow landing at each edge's own
+    slot, and the re-claim fallback."""
+    cfg, runs = _boundary_run(case)
+    spec = skt.make_spec("lsketch", n_shards=n_shards, config=cfg)
+    scan, pal = skt.create(spec), skt.create(spec)
+    for arrays in runs:
+        scan = skt.ingest(spec, scan, _batch(arrays), path="scan")
+        pal = skt.ingest(spec, pal, _batch(arrays), path="pallas")
+        assert _states_equal(scan.shards, pal.shards)
+    if case == "overflow":
+        assert int(jnp.sum(pal.shards.pool_lost)) > 0, \
+            "stream must saturate the pool"
+        pool_slots = jnp.any(pal.shards.pool_C != 0, axis=(0, 1))
+        assert bool(pool_slots[0]) and bool(pool_slots[1]), \
+            "pool weight must land at both subwindows' slots"
+
+
+WALKS = {"two": True, "k-1": True, "wraps": True, "overflow": True,
+         "reclaim": False}
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("case", list(WALKS))
+def test_walk_rule_and_scan_batches_counter(case, n_shards):
+    """``walk_eligible`` takes the walk for the boundary cases that
+    re-claim no slot and the scan for an in-batch re-claim, batch by batch
+    against the ring as ingest leaves it; the host's
+    ``ingest.scan_batches`` counter agrees with it."""
+    from repro.sketch import trace
+    from repro.sketch.ingest import _dispatch_stacked, _partition_stack
+
+    cfg, runs = _boundary_run(case)
+    spec = skt.make_spec("lsketch", n_shards=n_shards, config=cfg)
+    state = skt.create(spec)
+    plan = jax.jit(jax.vmap(functools.partial(eng_insert.ring_plan, cfg)))
+    trace.reset()
+    trace.enable()
+    try:
+        n_scan = 0
+        for arrays in runs:
+            stacked, n_valid = _partition_stack(spec, _batch(arrays))
+            sh = state.shards
+            valid, _, p = plan(sh.slot_widx, sh.cur_widx, stacked.time,
+                               n_valid)
+            walks = bool(eng_insert.walk_eligible(p, valid))
+            assert walks == WALKS[case]
+            n_scan += not walks
+            state = _dispatch_stacked(spec, state, stacked, n_valid, "auto")
+        counts = trace.snapshot()["counts"]
+    finally:
+        trace.disable()
+        trace.reset()
+    assert counts["ingest.scan_batches"] == n_scan
+    assert counts["ingest.boundary_batches"] == len(runs)
+
+
+@pytest.mark.parametrize("case", ["two", "reclaim"])
+def test_fused_pallas_matches_scan_across_boundary(case):
+    """The single-shard fused entry follows the same walk rule: a
+    two-subwindow batch takes the walk with per-edge slots, a re-claim
+    the scan, and both equal the scan and the chunked reference."""
+    cfg, (arrays,) = _boundary_run(case)
+    batch = _batch(arrays)
+    ref = eng_insert.insert_batch(cfg, init_state(cfg), batch, path="chunked")
+    for path in ("scan", "pallas"):
+        got = eng_insert.insert_batch(cfg, init_state(cfg), batch, path=path)
+        assert _states_equal(ref, got), path
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
@@ -511,7 +626,7 @@ def test_small_max_bin_drops_overflow_to_pool_on_both_lowerings():
     assert int(jnp.sum(xla[1] > 0)) <= S * CFG.n_blocks ** 2 * MB
     base = jax.tree.map(lambda x: jnp.stack([x] * S), init_state(CFG))
     out = jax.jit(lambda st: matrix_insert_binned_sharded(
-        CFG, st, probes, lei, w, jnp.zeros((S,), jnp.int32),
+        CFG, st, probes, lei, w, jnp.zeros((S, B), jnp.int32),
         max_bin=MB))(base)
     # the cap must actually bite: some edges landed in the pool
     assert int(jnp.sum(out.pool_key[..., 0] != EMPTY)) > 0

@@ -104,14 +104,15 @@ def test_boundary_batches_match_a_hand_count(on):
         _partition_stack(spec, _batch(rng, times))
     counts = trace.snapshot()["counts"]
     assert counts["ingest.boundary_batches"] == sum(crosses)
+    assert counts["ingest.scan_batches"] == 0  # none re-claims a slot
     assert counts["ingest.batches"] == len(runs)
     assert counts["ingest.rows"] == 80 * len(runs)
 
 
 def test_boundary_is_per_shard_and_pad_rows_at_two_shards(on):
     """A batch that spans a boundary only across shards (each shard's rows
-    in one subwindow) takes the key walk, so it is no boundary batch; the
-    pad rows are the stacked rows that ``n_valid`` masks."""
+    in one subwindow) is no boundary batch: it can record a plane delta;
+    the pad rows are the stacked rows that ``n_valid`` masks."""
     rng = np.random.default_rng(3)
     spec = skt.SketchSpec(kind="lsketch", config=CFG, n_shards=2)
     cand = np.arange(2000)
