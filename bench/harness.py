@@ -15,6 +15,7 @@ from __future__ import annotations
 import gc
 import importlib.util
 import json
+import re
 import shutil
 import sys
 import tempfile
@@ -32,6 +33,10 @@ ROOT = BENCH.parent
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 SKETCH_KEYS = ("d", "n_blocks", "F", "r", "s", "c", "k", "window_size",
                "pool_capacity", "pool_probes", "seed")
+# an open loop's query latency percentiles: those the log shows; a cell
+# reports each ``query_p<n>_ms`` that BENCHMARK.json names for it
+QUERY_PERCENTILES = (50, 90, 95, 99)
+QUERY_METRIC = re.compile(r"query_p(\d+)_ms")
 
 
 def load_json(path: Path) -> dict:
@@ -206,8 +211,18 @@ def state_mismatches(got: dict, want: dict) -> dict:
     return out
 
 
-def load_metric(name: str):
+def metric_file(name: str) -> Path:
+    """The reader of per-layer metric ``name``: ``metrics/<name>.py``, or,
+    for ``<base>.<part>`` (one quantity split by the end-to-end metric
+    each cell reports) with no file of its own, ``metrics/<base>.py``."""
     path = BENCH / "metrics" / f"{name}.py"
+    if path.exists():
+        return path
+    return BENCH / "metrics" / f"{name.split('.')[0]}.py"
+
+
+def load_metric(name: str):
+    path = metric_file(name)
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
@@ -393,11 +408,21 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
               "ingest_edges_per_s": res["edges"] / (res["t1"] - res["t0"])}
     if not plan.closed:
         lat = np.where(res["failed_mask"], np.inf, res["latency_s"]) * 1e3
-        values["query_p50_ms"] = float(np.percentile(lat, 50))
-        values["query_p95_ms"] = float(np.percentile(lat, 95))
+        for x in cell.end_to_end():
+            named = QUERY_METRIC.fullmatch(x["name"])
+            if named:
+                values[x["name"]] = float(np.percentile(lat,
+                                                        int(named[1])))
         late = res["ingest_late_s"]
-        log(f"queries: {len(lat)}; ingest batches late by p50 "
-            f"{np.median(late) * 1e3:.3f} ms, max {late.max() * 1e3:.3f} ms")
+        log(f"queries: {len(lat)} in {len(m.spans.times.get('flush', []))} "
+            "flushes, latency " + ", ".join(
+                f"p{p} {v:.3f} ms" for p, v in zip(
+                    QUERY_PERCENTILES, np.percentile(lat, QUERY_PERCENTILES)))
+            + f", max {lat.max():.3f} ms; ingest batches late by p50 "
+            f"{np.median(late) * 1e3:.3f} ms, max {late.max() * 1e3:.3f} ms "
+            "(first half of the window "
+            f"{late[:len(late) // 2].max(initial=0) * 1e3:.3f}, second "
+            f"{late[len(late) // 2:].max(initial=0) * 1e3:.3f})")
     dev = m.device
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": m.peak}

@@ -17,7 +17,9 @@ offers the same number), with kinds in the exact ``mix`` shares. Each
 query takes its endpoints from one of the ``recent_edges`` edges due last
 before it. The loop submits every due ingest batch, then up to
 ``max_per_flush`` due queries, then flushes; a query's latency runs from
-its due time until its answer is on the host.
+its due time until its answer is on the host, of which the part until
+its flush is taken up (behind ingest calls and earlier flushes) is its
+queue time.
 """
 
 from __future__ import annotations
@@ -211,6 +213,7 @@ def open_window(server, plan: Plan, spans: Spans, sync) -> dict:
     nq = len(due_q)
     answers = np.full(nq, -1, np.int64)
     done_at = np.full(nq, np.nan)
+    start_at = np.full(nq, np.nan)
     n_at = np.zeros(nq, np.int64)
     failed = np.zeros(nq, bool)
     late_b = np.zeros(nb)
@@ -230,6 +233,7 @@ def open_window(server, plan: Plan, spans: Spans, sync) -> dict:
         while hi < nq and due_q[hi] <= now and hi - qi < plan.max_per_flush:
             hi += 1
         if hi > qi:
+            start_at[qi:hi] = now
             with spans("submit"):
                 reqs = [server.submit(q["reqs"][j][0], **q["reqs"][j][1])
                         for j in range(qi, hi)]
@@ -245,6 +249,8 @@ def open_window(server, plan: Plan, spans: Spans, sync) -> dict:
             n_at[qi:hi] = edges
             qi = hi
             continue
+        if bi == nb and qi == nq:  # the schedule ended on an ingest batch
+            break
         nxt = min(due_b[bi] if bi < nb else math.inf,
                   due_q[qi] if qi < nq else math.inf)
         wait = nxt - (CLOCK() - t0)
@@ -257,6 +263,7 @@ def open_window(server, plan: Plan, spans: Spans, sync) -> dict:
     lat = done_at - due_q
     return {"t0": t0, "t1": t1, "batches": nb, "edges": nb * plan.batch,
             "answers": answers, "n_at": n_at, "latency_s": lat,
+            "queue_s": start_at - due_q,
             "failed": int(failed.sum()), "failed_mask": failed,
             "ingest_late_s": late_b, "ran_dry": False}
 

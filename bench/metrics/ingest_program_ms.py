@@ -1,7 +1,8 @@
 """Stacked ingest dispatch: device time of ``_ingest_stacked_lsketch``
-(key walk or boundary scan, ring reclaim, delta slices) per launch, from
-the device trace. Each ingest call launches the program once (the call
-dispatches the batch staged by the one before it); where the traced
+(key walk, or the scan for in-batch slot re-claims; ring reclaim, delta
+slices) per launch, from the device trace. Each ingest call's batch is
+launched once (by the next ingest call in a closed loop, by the next
+flush in an open one, which waits for it); where the traced
 window holds launches that differ from its ingest calls by more than one
 at each end (events dropped inside it), the metric is left out rather
 than averaged over the launches that survived."""

@@ -1,6 +1,7 @@
 """Insert kernel: device time of the Mosaic ``sketch_insert_walk`` kernel
-per launch, from the device trace. Batches that span a subwindow boundary
-take the scan instead and launch no kernel."""
+per launch, from the device trace. Every batch launches it, those that
+span a subwindow boundary too; only a batch in which some shard row
+re-claims a ring slot takes the scan instead and launches no kernel."""
 
 
 def read(ctx):

@@ -1,9 +1,11 @@
 """Insert kernel: share of the HBM roofline. The least bytes of one
 launch (``rooflines.walk_bytes``: the touched key tiles read and written
 once, the probe arrays read once), averaged over the batches of the
-traced window (as far as the device recorded it) that took the walk (one
-subwindow each), at the peak bandwidth, over the kernel's mean device
-time per launch in the trace."""
+traced window (as far as the device recorded it) that lie in one
+subwindow, at the peak bandwidth, over the kernel's mean device time per
+launch in the trace. Batches that span a subwindow boundary launch the
+kernel too but are left out of the average; their least bytes hardly
+differ."""
 
 import numpy as np
 

@@ -1,6 +1,7 @@
 """Plane cache under ingest: device time of ``_build_planes`` and
-``_apply_planes_delta`` (the serving prewarm after every batch) per ingest
-call in the traced window, from the device trace."""
+``_apply_planes_delta`` (the serving prewarm after every batch: inside
+the next ingest call in a closed loop, inside the next flush in an open
+one) per ingest call in the traced window, from the device trace."""
 
 PROGRAMS = ("_build_planes", "_apply_planes_delta")
 

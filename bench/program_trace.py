@@ -233,10 +233,10 @@ READINGS = {
     # operations no scope names (copies XLA inserts, for one)
     "ring_reclaim_ms": lambda snap, sp: _per_launch_ms(sp, "ingest.reclaim"),
     "ingest_unscoped_ms": lambda snap, sp: _per_launch_ms(sp, "unscoped"),
-    # share of the batches partitioned whose shard rows span subwindows:
-    # the launches that take the boundary scan, not the key walk
+    # share of the batches partitioned in which some shard row re-claims a
+    # ring slot: the launches that take the scan, not the key walk
     "scan_batch_share": lambda snap, sp: _share_pct(
-        snap, "ingest.boundary_batches", "ingest.batches"),
+        snap, "ingest.scan_batches", "ingest.batches"),
 }
 
 

@@ -112,15 +112,23 @@ def test_ingest_and_plane_programs(chip, tpu_lowering, config):
                                    on(chip, delta), horizon=k).compile())
 
 
+def bucket(n: int) -> int:
+    """The query frontend's pad bucket for ``n`` rows (floor 32)."""
+    b = 32
+    while b < n:
+        b *= 2
+    return b
+
+
 def query_groups():
-    """(traffic, group, pad bucket) for every group a mix can flush."""
-    out = []
+    """(traffic, group, pad bucket) for every group a mix can flush: the
+    post-window check's groups, and an open loop's groups at its largest
+    flush."""
     chk = mix("closed_ingest")["check"]
-    for g in chk["groups"]:
-        n = 32
-        while n < chk["per_group"]:
-            n *= 2
-        out.append(("closed_ingest", g, n))
+    out = [("closed_ingest", g, bucket(chk["per_group"]))
+           for g in chk["groups"]]
+    q = mix("serve_point")["queries"]
+    out += [("serve_point", g, bucket(q["max_per_flush"])) for g in q["mix"]]
     return out
 
 

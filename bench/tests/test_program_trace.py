@@ -122,7 +122,8 @@ def test_per_call_sums_and_self_times():
 
 def test_scan_batch_share_reads_the_partition_counters():
     snap = {"spans": [], "counts": {"ingest.batches": 8,
-                                    "ingest.boundary_batches": 3}}
+                                    "ingest.boundary_batches": 5,
+                                    "ingest.scan_batches": 3}}
     assert program_trace.readings(snap, None)["scan_batch_share"] == \
         pytest.approx(37.5)
     snap["counts"] = {"ingest.batches": 4}
